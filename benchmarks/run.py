@@ -604,6 +604,9 @@ def main() -> None:
                          "Chrome-trace JSON (load in ui.perfetto.dev)")
     ap.add_argument("--only", default="")
     args = ap.parse_args()
+    from repro import compile_cache
+
+    compile_cache.enable()
 
     @contextlib.contextmanager
     def traced():

@@ -28,6 +28,11 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def _dot(a, b):
+    # full f32: the TPU's default precision would round to bf16
+    return jnp.vdot(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 class CGResult(NamedTuple):
     x: jax.Array
     iters: jax.Array
@@ -41,7 +46,7 @@ def cg_solve(matvec: Callable, b: jax.Array, max_iter: int = 100,
     x0 = jnp.zeros_like(b)
     r0 = b - matvec(x0)
     p0 = r0
-    rs0 = jnp.vdot(r0, r0)
+    rs0 = _dot(r0, r0)
 
     def cond(state):
         _, _, _, rs, k = state
@@ -50,10 +55,10 @@ def cg_solve(matvec: Callable, b: jax.Array, max_iter: int = 100,
     def body(state):
         x, r, p, rs, k = state
         ap = matvec(p)
-        alpha = rs / jnp.vdot(p, ap)
+        alpha = rs / _dot(p, ap)
         x = x + alpha * p
         r = r - alpha * ap
-        rs_new = jnp.vdot(r, r)
+        rs_new = _dot(r, r)
         p = r + (rs_new / rs) * p
         return (x, r, p, rs_new, k + 1)
 
@@ -145,11 +150,11 @@ def cg_measured(matvec: Callable, b: jax.Array, iters: int = 20,
 
     @jax.jit
     def vec_update(x, r, p, ap, rs_old):
-        pap = jnp.vdot(p, ap)
+        pap = _dot(p, ap)
         alpha = rs_old / pap
         x = x + alpha * p
         r = r - alpha * ap
-        rs_new = jnp.vdot(r, r)
+        rs_new = _dot(r, r)
         beta = rs_new / rs_old
         p = r + beta * p
         return x, r, p, rs_new
@@ -157,7 +162,7 @@ def cg_measured(matvec: Callable, b: jax.Array, iters: int = 20,
     x = jnp.zeros_like(b)
     r = b
     p = r
-    rs = jnp.vdot(r, r)
+    rs = _dot(r, r)
     times = []
     for i in range(iters + warmup):
         t0 = time.perf_counter()

@@ -27,7 +27,11 @@ plan's composed permutation AND the panel-padding map), supports
 `matmul(X[n, k])` and CG, round-trips through the content-addressed plan
 store, and runs on a real device mesh when the process has enough devices
 (XLA_FLAGS=--xla_force_host_platform_device_count=8 in tests/CI) or on a
-bit-equivalent single-device simulation otherwise (`op.simulated`).
+bit-equivalent single-device simulation otherwise (`op.simulated`, which
+logs a warning and counts `sharded.simulated` when it is not forced). The
+mesh takes the first devices of the process unless the build is given its
+own (`Plan.build(devices=)`; the router gives each mesh a slice); the
+panels are placed on the mesh once, with a NamedSharding.
 
 The pre-PR-5 entry points (plan_1d / spmv_1d / plan_2d / spmv_2d /
 plan_halo_1d / spmv_halo_1d) remain as DeprecationWarning shims over the
@@ -35,19 +39,22 @@ legacy internals with no in-src callers; see the README migration table.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import logging
 import warnings
 from typing import Optional, Tuple
 
 import numpy as np
 
-from ... import obs
+from ... import compile_cache, obs
 from ..sparse.bell import to_block_ell
 from ..sparse.csr import CSRMatrix
 from ..sparse.partition import (nnz_balanced_partition, partition_to_owner,
                                 static_partition)
 from .topology import Topology, padded_panel_rows
 
+_log = logging.getLogger(__name__)
 
 # ---------------------------------------------------------------------------
 # Sharded layout: host-side arrays for one (matrix, topology, partition)
@@ -220,13 +227,15 @@ def build_sharded_layout(rmat: CSRMatrix, topology: Topology,
 def _bell_local(blocks, bcols, xw, bn):
     """One device's Block-ELL panel SpMM: xw [win, nv] -> y [h_pad, nv].
     Accumulates at promote(x.dtype, f32) so fp64 plans keep fp64."""
+    import jax
     import jax.numpy as jnp
 
     x2d = xw.reshape(-1, bn, xw.shape[-1])
     gathered = x2d[bcols]                            # [nbr, K, bn, nv]
     acc = jnp.promote_types(xw.dtype, jnp.float32)
     y = jnp.einsum("rkij,rkjv->riv", blocks, gathered,
-                   preferred_element_type=acc).astype(xw.dtype)
+                   preferred_element_type=acc,
+                   precision=jax.lax.Precision.HIGHEST).astype(xw.dtype)
     return y.reshape(-1, xw.shape[-1])
 
 
@@ -247,6 +256,8 @@ def _local_y(engine, arrs: tuple, xw, h_pad: int, bn: int):
 
 _ARRAY_ORDER = {"bell": ("blocks", "block_cols"),
                 "csr": ("row_ids", "cols", "vals")}
+# the ShardedOperator's gather (x in) and scatter (y out) index maps
+_INDEX_MAPS = ("_in_idx", "_in_idx_r", "_out_idx", "_out_idx_r")
 
 
 # ---------------------------------------------------------------------------
@@ -283,12 +294,18 @@ class ShardedOperator:
     enough devices, otherwise a single-device simulation (`op.simulated`)
     that runs the identical local kernels over a vmapped panel axis —
     same math, no mesh — so sharded plans stay usable (service, CG,
-    verification) in single-device processes.
+    verification) in single-device processes. An unforced simulation is
+    logged and counted (`sharded.simulated`), never silent.
     """
 
     def __init__(self, layout: ShardedLayout, perm: Optional[np.ndarray],
-                 plan=None, build_info: Optional[dict] = None):
+                 plan=None, build_info: Optional[dict] = None,
+                 devices=None):
         import jax.numpy as jnp
+
+        if devices is not None and len(devices) != layout.topology.devices:
+            raise ValueError(f"{len(devices)} devices for a "
+                             f"{layout.topology.devices}-device mesh")
 
         self.layout = layout
         self.plan = plan
@@ -314,6 +331,7 @@ class ShardedOperator:
         self._dev = None                        # engine arrays, lazy
         self._dtype = None
         self._fns = {}                          # nv -> jitted step
+        self._devices = None if devices is None else tuple(devices)
         self.force_simulated = False            # testing/debug override
 
     # -- facade surface ----------------------------------------------------
@@ -345,33 +363,61 @@ class ShardedOperator:
     def simulated(self) -> bool:
         import jax
 
-        return (self.force_simulated
-                or len(jax.devices()) < self.layout.topology.devices)
+        return self.force_simulated or (
+            self._devices is None
+            and len(jax.devices()) < self.layout.topology.devices)
+
+    @property
+    def devices(self) -> list:
+        """The devices of this operator's mesh (empty when simulated)."""
+        import jax
+
+        if self.simulated:
+            return []
+        if self._devices is not None:
+            return list(self._devices)
+        return list(jax.devices()[:self.layout.topology.devices])
 
     def unwrap(self) -> _ReorderedView:
         return _ReorderedView(self)
 
     # -- execution ---------------------------------------------------------
     def _device_arrays(self, dtype):
+        """Engine arrays on the device(s): each device's panels placed
+        once on the mesh (leading axes = mesh axes), or on the default
+        device when simulated. On a mesh the gather/scatter index maps
+        move there too, one copy per device, so a mesh that does not hold
+        the default device never reads from it."""
+        import jax
         import jax.numpy as jnp
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
 
         if self._dev is None or self._dtype != dtype:
             lay = self.layout
+            sharding = (None if self.simulated else
+                        NamedSharding(self._mesh(), P(*lay.topology.mesh_axes)))
+            if sharding is not None:
+                rep = NamedSharding(sharding.mesh, P())
+                for name in _INDEX_MAPS:
+                    setattr(self, name,
+                            jax.device_put(getattr(self, name), rep))
             dev = []
             for name in _ARRAY_ORDER[lay.engine]:
                 a = lay.arrays[name]
-                dev.append(jnp.asarray(
-                    a, dtype if np.issubdtype(a.dtype, np.floating) else None))
+                a = np.asarray(a, dtype if np.issubdtype(a.dtype, np.floating)
+                               else None)
+                dev.append(jnp.asarray(a) if sharding is None
+                           else jax.device_put(a, sharding))
             self._dev = tuple(dev)
             self._dtype = dtype
         return self._dev
 
     def _mesh(self):
-        import jax
         from jax.sharding import Mesh
 
         topo = self.layout.topology
-        devs = np.array(jax.devices()[:topo.devices])
+        devs = np.array(self.devices, dtype=object)
         return Mesh(devs.reshape(topo.mesh_shape), topo.mesh_axes)
 
     def _make_fn(self, nv: int):
@@ -379,7 +425,6 @@ class ShardedOperator:
         nv] for this batch width (mesh or simulated)."""
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         lay = self.layout
@@ -409,9 +454,9 @@ class ShardedOperator:
                                  h_pad, bn)
                     return y[None]
 
-                f = shard_map(body, mesh=mesh,
-                              in_specs=(P(ax),) * n_arr + (P(ax),),
-                              out_specs=P(ax))
+                f = jax.shard_map(body, mesh=mesh,
+                                  in_specs=(P(ax),) * n_arr + (P(ax),),
+                                  out_specs=P(ax))
 
                 def step(arrs, xp):
                     yp = f(*arrs, xp.reshape(d, h_pad, nv))
@@ -425,9 +470,9 @@ class ShardedOperator:
                                  h_pad, bn)
                     return jax.lax.psum(y, cax)[None]
 
-                f = shard_map(body, mesh=mesh,
-                              in_specs=(P(rax, cax),) * n_arr + (P(cax),),
-                              out_specs=P(rax))
+                f = jax.shard_map(body, mesh=mesh,
+                                  in_specs=(P(rax, cax),) * n_arr + (P(cax),),
+                                  out_specs=P(rax))
 
                 def step(arrs, xp):
                     yp = f(*arrs, xp.reshape(mm, seg_n, nv))
@@ -467,16 +512,24 @@ class ShardedOperator:
         return jax.jit(lambda arrs, xp: step(arrs, xp))
 
     def _exec(self, x, permuted: bool, batched: bool):
+        import jax
         import jax.numpy as jnp
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
 
         lay = self.layout
         with obs.span("sharded.spmv", engine=lay.engine,
                       schedule=lay.schedule, devices=lay.topology.devices,
-                      simulated=self.simulated):
-            x = jnp.asarray(x)
+                      simulated=self.simulated), self._compile_scope():
+            if self.simulated:
+                x = jnp.asarray(x)
+            else:
+                # the gather runs where the panels live
+                x = jax.device_put(x, NamedSharding(self._mesh(), P()))
             x2 = x if batched else x[:, None]
             nv = int(x2.shape[1])
             dtype = x2.dtype
+            arrs = self._device_arrays(dtype)
             with obs.span("sharded.gather_x", schedule=lay.schedule):
                 zero = jnp.zeros((1, nv), dtype)
                 xe = jnp.concatenate([x2, zero], axis=0)
@@ -486,17 +539,33 @@ class ShardedOperator:
             key = (nv, self.simulated)
             fn = self._fns.get(key)
             if fn is None:
+                if self.simulated and not self.force_simulated:
+                    obs.counter("sharded.simulated").inc()
+                    _log.warning(
+                        "%d devices for a %d-device topology: sharded "
+                        "SpMV is simulated on one device", len(jax.devices()),
+                        lay.topology.devices)
                 fn = self._fns[key] = self._make_fn(nv)
             # one fused jit: per-device compute + the plan's collective
             # (all-gather / halo ring permutes / 2-D all-reduce)
             with obs.span("sharded.exec", schedule=lay.schedule,
                           halo=int(lay.halo)):
-                yp = fn(self._device_arrays(dtype), xp)
+                yp = fn(arrs, xp)
             with obs.span("sharded.scatter_y", schedule=lay.schedule):
                 y = jnp.take(yp,
                              self._out_idx_r if permuted else self._out_idx,
                              axis=0)
             return y if batched else y[:, 0]
+
+    def _compile_scope(self):
+        """A mesh off the process's first devices compiles its programs
+        without persisting them (see repro.compile_cache)."""
+        import jax
+
+        devs = self._devices
+        if devs is None or list(devs) == jax.devices()[:len(devs)]:
+            return contextlib.nullcontext()
+        return compile_cache.not_persisted()
 
     def __call__(self, x, permuted: bool = False):
         return self._exec(x, permuted, batched=getattr(x, "ndim", 1) == 2)
@@ -520,7 +589,7 @@ class ShardedOperator:
 
     @classmethod
     def from_state(cls, meta, arrays, dtype=None, perm=None, plan=None,
-                   build_info=None):
+                   build_info=None, devices=None):
         topo = Topology.from_json(meta["topology"])
         starts = np.asarray(arrays["panel_starts"], np.int64)
         m = int(meta["shape"][0])
@@ -534,7 +603,8 @@ class ShardedOperator:
             halo=int(meta["halo"]), h_pad=int(meta["h_pad"]),
             n_pad=int(meta["n_pad"]), seg_n=int(meta["seg_n"]),
             block_shape=tuple(meta["block_shape"]))
-        return cls(layout, perm, plan=plan, build_info=build_info)
+        return cls(layout, perm, plan=plan, build_info=build_info,
+                   devices=devices)
 
 
 # ---------------------------------------------------------------------------
@@ -583,7 +653,6 @@ def _legacy_plan_1d(mat: CSRMatrix, num_devices: int, bm: int = 8,
 
 def _legacy_spmv_1d(mesh, axis_names: Tuple[str, ...]):
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from . import ref
@@ -596,8 +665,8 @@ def _legacy_spmv_1d(mesh, axis_names: Tuple[str, ...]):
         y = ref.spmv_bell(blocks[0], block_cols[0], xs.reshape(-1, bn, 1))
         return y.reshape(1, -1)
 
-    f = shard_map(local, mesh=mesh,
-                  in_specs=(P(ax), P(ax), P(ax)), out_specs=P(ax))
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(P(ax), P(ax), P(ax)), out_specs=P(ax))
     return jax.jit(f)
 
 
@@ -639,7 +708,6 @@ def _legacy_plan_2d(mat: CSRMatrix, d: int, m_axis: int, bm: int = 8,
 
 def _legacy_spmv_2d(mesh, row_axis: str = "data", col_axis: str = "model"):
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from . import ref
@@ -651,10 +719,10 @@ def _legacy_spmv_2d(mesh, row_axis: str = "data", col_axis: str = "model"):
         y = jax.lax.psum(y, col_axis)
         return y.reshape(1, -1)
 
-    f = shard_map(local, mesh=mesh,
-                  in_specs=(P(row_axis, col_axis), P(row_axis, col_axis),
-                            P(col_axis)),
-                  out_specs=P(row_axis))
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(P(row_axis, col_axis), P(row_axis, col_axis),
+                                P(col_axis)),
+                      out_specs=P(row_axis))
     return jax.jit(f)
 
 
@@ -697,7 +765,6 @@ def _legacy_plan_halo_1d(mat: CSRMatrix, num_devices: int, bm: int = 8,
 def _legacy_spmv_halo_1d(mesh, axis_names: Tuple[str, ...], halo: int):
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from . import ref
@@ -718,8 +785,8 @@ def _legacy_spmv_halo_1d(mesh, axis_names: Tuple[str, ...], halo: int):
         y = ref.spmv_bell(blocks[0], block_cols[0], xw.reshape(-1, bn, 1))
         return y.reshape(1, -1)
 
-    f = shard_map(local, mesh=mesh,
-                  in_specs=(P(ax), P(ax), P(ax)), out_specs=P(ax))
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(P(ax), P(ax), P(ax)), out_specs=P(ax))
     return jax.jit(f)
 
 

@@ -121,8 +121,10 @@ def operator_nbytes_per_device(op) -> list:
                     else a.dtype.itemsize)
         per += np.asarray([flat[i].size * itemsize for i in range(ndev)],
                           dtype=np.int64)
+    from .distributed import _INDEX_MAPS
+
     replicated = 0
-    for name in ("_in_idx", "_in_idx_r", "_out_idx", "_out_idx_r"):
+    for name in _INDEX_MAPS:
         arr = getattr(op, name, None)
         if arr is not None:
             replicated += int(arr.nbytes)
@@ -230,10 +232,10 @@ def build_cached(mat: CSRMatrix, engine: str = "auto", dtype=None,
             # restored kernel choice must match THIS process's backend (an
             # entry written on TPU may be reloaded on CPU and vice versa)
             if getattr(op, "use_kernel", None) is not None:
-                import jax
+                from ...kernels import resolve_use_kernel
 
-                op.use_kernel = use_kernel if use_kernel != "auto" else (
-                    "pallas" if jax.default_backend() == "tpu" else "ref")
+                op.use_kernel = resolve_use_kernel(
+                    use_kernel, plan.engine if plan else engine)
             info.update(cache_hit=True,
                         load_ms=(time.perf_counter() - t0) * 1e3,
                         engine=plan.engine if plan else engine,

@@ -136,8 +136,9 @@ class DeviceELL:
         j = np.arange(mat.nnz) - np.repeat(rp[:-1], counts)
         cols[r, j] = mat.cols
         vals[r, j] = mat.vals
-        self.ell_cols = jnp.asarray(cols)
-        self.ell_vals = jnp.asarray(vals, dtype=dtype)
+        # slot-major [K, m] on the device (ref.spmv_ell)
+        self.ell_cols = jnp.asarray(cols.T)
+        self.ell_vals = jnp.asarray(vals.T, dtype=dtype)
         self.padded_nnz = mat.m * k
 
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -153,16 +154,18 @@ class DeviceELL:
 
     def state(self):
         meta = {"m": self.m, "n": self.n, "padded_nnz": self.padded_nnz}
-        return meta, {"ell_cols": np.asarray(self.ell_cols),
-                      "ell_vals": np.asarray(self.ell_vals)}
+        # keys name the layout: a row-major entry written before the
+        # slot-major switch fails to load and is rebuilt
+        return meta, {"slot_cols": np.asarray(self.ell_cols),
+                      "slot_vals": np.asarray(self.ell_vals)}
 
     @classmethod
     def from_state(cls, meta, arrays, dtype=jnp.float32):
         op = object.__new__(cls)
         op.m, op.n = meta["m"], meta["n"]
         op.padded_nnz = meta["padded_nnz"]
-        op.ell_cols = jnp.asarray(arrays["ell_cols"])
-        op.ell_vals = jnp.asarray(arrays["ell_vals"], dtype=dtype)
+        op.ell_cols = jnp.asarray(arrays["slot_cols"])
+        op.ell_vals = jnp.asarray(arrays["slot_vals"], dtype=dtype)
         return op
 
 

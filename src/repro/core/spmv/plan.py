@@ -451,28 +451,30 @@ class Plan:
         # restored kernel choice must match THIS process's backend (an
         # entry written on TPU may be reloaded on CPU and vice versa)
         if getattr(op, "use_kernel", None) is not None:
-            import jax
+            from ...kernels import resolve_use_kernel
 
-            op.use_kernel = self.use_kernel if self.use_kernel != "auto" \
-                else ("pallas" if jax.default_backend() == "tpu" else "ref")
+            op.use_kernel = resolve_use_kernel(self.use_kernel,
+                                               self.tune.engine)
         op.plan = self.tune
         return op
 
-    def build(self, cache: bool = True):
+    def build(self, cache: bool = True, devices=None):
         """Materialize the operator this plan describes: a permutation-
         carrying Operator for single-device plans, a ShardedOperator for
-        topology-aware plans (perm + panel starts + collective schedule).
-        Store hit -> device arrays reload (load_ms); miss -> permute +
-        format conversion (build_ms) and the complete entry (plan + perm
-        + operator payload) is persisted. Never re-tunes."""
+        topology-aware plans (perm + panel starts + collective schedule)
+        whose mesh is `devices` (default: the process's first ones;
+        single-device plans ignore it). Store hit -> device arrays reload
+        (load_ms); miss -> permute + format conversion (build_ms) and the
+        complete entry (plan + perm + operator payload) is persisted.
+        Never re-tunes."""
         with obs.span("plan.build", key=self.key, scheme=self.scheme,
                       engine=self.tune.engine) as sp:
-            op = self._build_impl(cache)
+            op = self._build_impl(cache, devices)
             info = getattr(op, "build_info", None) or {}
             sp.set(cache_hit=bool(info.get("cache_hit")))
             return op
 
-    def _build_impl(self, cache: bool):
+    def _build_impl(self, cache: bool, devices):
         import jax.numpy as jnp
 
         dt = jnp.dtype(self.dtype_name)
@@ -481,7 +483,7 @@ class Plan:
                 "engine": self.tune.engine, "plan": self.tune.to_json()}
         use_store = cache and store_enabled()
         if self.topology is not None:
-            return self._build_sharded(dt, info, use_store)
+            return self._build_sharded(dt, info, use_store, devices)
         inner = None
         if use_store:
             t0 = time.perf_counter()
@@ -506,7 +508,8 @@ class Plan:
                 self.save(op=inner)
         return Operator(inner, self.perm, self, build_info=info)
 
-    def rebuild(self, mat: CSRMatrix, use_kernel: Optional[str] = None):
+    def rebuild(self, mat: CSRMatrix, use_kernel: Optional[str] = None,
+                devices=None):
         """Operator for a matrix with the SAME sparsity structure but
         (possibly) different values, under this plan's frozen decision:
         permute through the carried perm, convert with the already-chosen
@@ -519,8 +522,9 @@ class Plan:
         never a replan. Sharded plans rebuild too: the frozen partition,
         panel split and collective schedule are reused and only the
         per-device arrays are repacked (no re-partition, no re-tune) —
-        what the multi-shard router's value-swap path runs. Raises
-        ValueError on a structure mismatch."""
+        what the multi-shard router's value-swap path runs, on the mesh
+        `devices` as in `build`. Raises ValueError on a structure
+        mismatch."""
         import jax.numpy as jnp
 
         if tuple(mat.shape) != tuple(self.mat_shape) \
@@ -552,7 +556,8 @@ class Plan:
                         "comm": dict(self.comm),
                         "partitioner": self.partitioner}
                 return distributed.ShardedOperator(
-                    layout, self.perm, plan=self, build_info=info)
+                    layout, self.perm, plan=self, build_info=info,
+                    devices=devices)
             inner = tune_mod.build_from_plan(
                 rmat, self.tune, dtype=dt,
                 use_kernel=(self.use_kernel if use_kernel is None
@@ -591,7 +596,7 @@ class Plan:
             kw["max_bw_growth"] = max_bw_growth
         return delta_mod.apply_delta(self, delta, **kw)
 
-    def _build_sharded(self, dt, info: dict, use_store: bool):
+    def _build_sharded(self, dt, info: dict, use_store: bool, devices):
         """Topology-aware build: restore the ShardedOperator's layout
         arrays from the plan store when possible, otherwise chop the
         reordered matrix into per-device arrays and persist the entry."""
@@ -611,7 +616,7 @@ class Plan:
                     try:
                         op = distributed.ShardedOperator.from_state(
                             op_rec["meta"], arrays, perm=self.perm,
-                            plan=self, build_info=info)
+                            plan=self, build_info=info, devices=devices)
                         info["load_ms"] = (time.perf_counter() - t0) * 1e3
                         info["cache_hit"] = True
                         return op
@@ -624,7 +629,7 @@ class Plan:
             schedule=self.comm.get("schedule", "all_gather"),
             halo=int(self.comm.get("halo", 0)))
         op = distributed.ShardedOperator(layout, self.perm, plan=self,
-                                         build_info=info)
+                                         build_info=info, devices=devices)
         info["build_ms"] = (time.perf_counter() - t0) * 1e3
         if use_store:
             self.save(op=op)

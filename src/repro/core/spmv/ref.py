@@ -9,6 +9,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# Contractions run at full f32: the TPU's default precision rounds f32
+# operands to bf16 on the MXU, which an f32 operator must not do.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def spmv_dense(a_dense: jax.Array, x: jax.Array) -> jax.Array:
     return a_dense @ x
@@ -39,26 +43,33 @@ def spmm_csr(row_ids: jax.Array, cols: jax.Array, vals: jax.Array,
 
 
 def spmv_ell(ell_cols: jax.Array, ell_vals: jax.Array, x: jax.Array) -> jax.Array:
-    """ELLPACK: ell_cols/vals [m, K], padding has val 0 (col arbitrary)."""
-    return jnp.sum(ell_vals * x[ell_cols], axis=1)
+    """ELLPACK, slot-major: ell_cols/vals [K, m] (slot j of every row is
+    one contiguous row of the arrays), padding has val 0 (col arbitrary).
+
+    Slot-major keeps the long axis minor: on a TPU an [m, K] array pads
+    K to 128 lanes, and its scalar gather took the v5e compiler ~100 s at
+    a million rows where [K, m] takes about one."""
+    return jnp.sum(ell_vals * x[ell_cols], axis=0)
 
 
 def spmm_ell(ell_cols: jax.Array, ell_vals: jax.Array, x: jax.Array) -> jax.Array:
-    """Batched ELLPACK: x [n, k] -> y [m, k] (one pass over the pads).
+    """Batched slot-major ELLPACK: ell_cols/vals [K, m], x [n, k] ->
+    y [m, k] (one pass over the pads).
 
     Accumulates at >= the operator dtype (f32 floor), so an f64 operator's
     matmul keeps f64 accuracy like its SpMV __call__ does.
 
-    Peak memory is the gathered [m, K, k] intermediate — the same
+    Peak memory is the gathered [K, m, k] intermediate — the same
     footprint class as spmm_csr's [nnz, k] in ELL's intended near-uniform
     regime (K ~ mean row nnz); on padding-inflated matrices the tuner
     never picks ELL in the first place.
     """
     out_dtype = jnp.promote_types(ell_vals.dtype, x.dtype)   # == __call__'s
     acc = jnp.promote_types(out_dtype, jnp.float32)
-    gathered = x[ell_cols]                           # [m, K, k]
-    return jnp.einsum("mj,mjv->mv", ell_vals, gathered,
-                      preferred_element_type=acc).astype(out_dtype)
+    gathered = x[ell_cols]                           # [K, m, k]
+    return jnp.einsum("jm,jmv->mv", ell_vals, gathered,
+                      preferred_element_type=acc,
+                      precision=HIGHEST).astype(out_dtype)
 
 
 def spmv_bell(blocks: jax.Array, block_cols: jax.Array, x2d: jax.Array) -> jax.Array:
@@ -70,7 +81,8 @@ def spmv_bell(blocks: jax.Array, block_cols: jax.Array, x2d: jax.Array) -> jax.A
     """
     gathered = x2d[block_cols]                       # [nbr, K, bn, nv]
     return jnp.einsum("rkij,rkjv->riv", blocks, gathered,
-                      preferred_element_type=jnp.float32).astype(x2d.dtype)
+                      preferred_element_type=jnp.float32,
+                      precision=HIGHEST).astype(x2d.dtype)
 
 
 def spmv_sell(chunk_vals: jax.Array, chunk_cols: jax.Array,
@@ -84,7 +96,8 @@ def spmv_sell(chunk_vals: jax.Array, chunk_cols: jax.Array,
     """
     gathered = x[chunk_cols]                         # [T, C, W, nv]
     partial = jnp.einsum("tcw,tcwv->tcv", chunk_vals, gathered,
-                         preferred_element_type=jnp.float32)
+                         preferred_element_type=jnp.float32,
+                         precision=HIGHEST)
     y = jax.ops.segment_sum(partial, chunk_slice, num_segments=num_slices,
                             indices_are_sorted=True)
     return y.astype(x.dtype)
@@ -95,7 +108,8 @@ def spmv_bcsr(blocks: jax.Array, block_rows: jax.Array, block_cols: jax.Array,
     """BCSR: blocks [T, bm, bn], block_rows/cols [T]. Returns [nbr, bm, nv]."""
     gathered = x2d[block_cols]                       # [T, bn, nv]
     partial = jnp.einsum("tij,tjv->tiv", blocks, gathered,
-                         preferred_element_type=jnp.float32)
+                         preferred_element_type=jnp.float32,
+                         precision=HIGHEST)
     y = jax.ops.segment_sum(partial, block_rows, num_segments=num_block_rows,
                             indices_are_sorted=True)
     return y.astype(x2d.dtype)

@@ -8,7 +8,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ... import obs
+from .. import resolve_use_kernel
 from ...core.sparse.bell import BCSR
+from ..segments import row_aligned_segments
 from .kernel import bcsr_spmm
 from .ref import bcsr_spmm_ref
 
@@ -47,9 +49,8 @@ class BcsrOperator:
         self.blocks = jnp.asarray(host.blocks, dtype=dtype)
         self.block_rows = jnp.asarray(host.block_rows, dtype=jnp.int32)
         self.block_cols = jnp.asarray(host.block_cols, dtype=jnp.int32)
-        if use_kernel == "auto":
-            use_kernel = "pallas" if jax.default_backend() == "tpu" else "ref"
-        self.use_kernel = use_kernel
+        self.segments = row_aligned_segments(host.block_rowptr)
+        self.use_kernel = resolve_use_kernel(use_kernel, "bcsr")
 
     def __call__(self, x: jax.Array) -> jax.Array:
         with obs.span("kernel.spmv", engine="bcsr",
@@ -63,10 +64,10 @@ class BcsrOperator:
                 .reshape(self.ncb, bn, nv)
             if self.use_kernel == "pallas":
                 y = bcsr_spmm(self.blocks, self.block_rows, self.block_cols,
-                              x2d, self.nbr)
+                              x2d, self.nbr, self.segments)
             elif self.use_kernel == "interpret":
                 y = bcsr_spmm(self.blocks, self.block_rows, self.block_cols,
-                              x2d, self.nbr, interpret=True)
+                              x2d, self.nbr, self.segments, interpret=True)
             else:
                 y = bcsr_spmm_ref(self.blocks, self.block_rows,
                                   self.block_cols, x2d, self.nbr)
@@ -102,4 +103,8 @@ class BcsrOperator:
         op.blocks = jnp.asarray(arrays["blocks"], dtype=dtype)
         op.block_rows = jnp.asarray(arrays["block_rows"])
         op.block_cols = jnp.asarray(arrays["block_cols"])
+        rowptr = np.zeros(op.nbr + 1, np.int64)
+        rowptr[1:] = np.cumsum(np.bincount(
+            np.asarray(arrays["block_rows"], np.int64), minlength=op.nbr))
+        op.segments = row_aligned_segments(rowptr)
         return op

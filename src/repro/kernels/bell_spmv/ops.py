@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ... import obs
+from .. import resolve_use_kernel
 from ...core.sparse.bell import BlockELL
 from .kernel import bell_spmm
 from .ref import bell_spmm_ref
@@ -27,10 +28,10 @@ class BellOperator:
         bm, bn = host.block_shape
         self.ncb = (host.shape[1] + bn - 1) // bn
         self.blocks = jnp.asarray(host.blocks, dtype=dtype)
-        self.block_cols = jnp.asarray(host.block_cols, dtype=jnp.int32)
-        if use_kernel == "auto":
-            use_kernel = "pallas" if jax.default_backend() == "tpu" else "ref"
-        self.use_kernel = use_kernel
+        # flat [nbr * K]: the kernel prefetches it 1-D (kernel.py)
+        self.block_cols = jnp.asarray(host.block_cols.reshape(-1),
+                                      dtype=jnp.int32)
+        self.use_kernel = resolve_use_kernel(use_kernel, "bell")
 
     def __call__(self, x: jax.Array) -> jax.Array:
         """x: [n] or [n, nv] -> y: [m] or [m, nv]."""
@@ -49,7 +50,9 @@ class BellOperator:
                 y = bell_spmm(self.blocks, self.block_cols, x2d,
                               interpret=True)
             else:
-                y = bell_spmm_ref(self.blocks, self.block_cols, x2d)
+                y = bell_spmm_ref(self.blocks,
+                                  self.block_cols.reshape(self.blocks.shape[:2]),
+                                  x2d)
             y = y.reshape(-1, nv)[: self.shape[0]]
             return y[:, 0] if squeeze else y
 
@@ -81,5 +84,5 @@ class BellOperator:
         op.ncb = meta["ncb"]
         op.use_kernel = meta["use_kernel"]
         op.blocks = jnp.asarray(arrays["blocks"], dtype=dtype)
-        op.block_cols = jnp.asarray(arrays["block_cols"])
+        op.block_cols = jnp.asarray(arrays["block_cols"]).reshape(-1)
         return op

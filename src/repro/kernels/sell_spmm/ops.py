@@ -1,9 +1,11 @@
 """Public wrapper for SELL-C-σ SpMM: dense RHS block in, dense block out.
 
 `sell_matmul(op, x)` is what `SellOperator.matmul` dispatches to for 2-D
-x — it handles k padding to the lane-aligned k-tile, the n padding, the
-σ-sort un-permute, and the pallas / interpret / jnp-ref engine choice,
-mirroring kernels/sell_spmv/ops.py for the single-vector path.
+x — it handles k padding to the k-tile, the σ-sort un-permute, and the
+pallas / interpret / jnp-ref engine choice, mirroring
+kernels/sell_spmv/ops.py for the single-vector path. Both paths run the
+one SELL kernel (kernels/sell_spmv/kernel.py); here the k-tile is wider
+than one vector, so the matrix stream is paid once per KB vectors.
 """
 from __future__ import annotations
 
@@ -11,8 +13,8 @@ import jax
 import jax.numpy as jnp
 
 from ... import obs
-from .kernel import sell_spmm_ktiled
-from .ref import sell_spmm_ref
+from ..sell_spmv.kernel import sell_spmm
+from ..sell_spmv.ref import sell_spmm_ref
 
 LANES = 128
 
@@ -36,21 +38,20 @@ def sell_matmul(op, x: jax.Array) -> jax.Array:
     path needs no alignment and runs on the exact k columns (small service
     batches would otherwise pay up to the tile floor in wasted flops).
     """
-    n, k = x.shape
+    k = x.shape[1]
     with obs.span("kernel.spmm", engine="sell", k=int(k),
                   use_kernel=op.use_kernel) as sp:
         if op.use_kernel in ("pallas", "interpret"):
             kb = pick_k_tile(k)
             sp.set(k_tile=int(kb))
             k_pad = ((k + kb - 1) // kb) * kb
-            xp = jnp.pad(x, ((0, op.n_pad - n), (0, k_pad - k)))
-            y = sell_spmm_ktiled(op.chunk_vals, op.chunk_cols,
-                                 op.chunk_slice, xp, op.num_slices, kb,
-                                 interpret=(op.use_kernel == "interpret"))
+            xp = jnp.pad(x, ((0, 0), (0, k_pad - k)))
+            y = sell_spmm(op.chunk_vals, op.chunk_cols, op.chunk_slice, xp,
+                          op.num_slices, kb=kb, segments=op.segments,
+                          interpret=(op.use_kernel == "interpret"))
         else:
-            xp = jnp.pad(x, ((0, op.n_pad - n), (0, 0)))
             y = sell_spmm_ref(op.chunk_vals, op.chunk_cols, op.chunk_slice,
-                              xp, op.num_slices)
+                              x, op.num_slices)
         # y is in slice order; inv_perm[r] = slice position of original
         # row r
         y = y.reshape(-1, y.shape[-1])[op.inv_perm]

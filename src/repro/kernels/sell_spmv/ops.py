@@ -1,7 +1,8 @@
 """Jit'd public wrapper for SELL-C-σ SpMV: host format in, vector out.
 
-Handles x padding, the σ-sort un-permute (inv_perm gather) and the
-pallas / interpret / jnp-ref engine choice, mirroring bell_spmv/ops.py.
+Handles the σ-sort un-permute (inv_perm gather), the SMEM segments of
+the chunk grid and the pallas / interpret / jnp-ref engine choice,
+mirroring bell_spmv/ops.py.
 """
 from __future__ import annotations
 
@@ -10,9 +11,19 @@ import jax.numpy as jnp
 import numpy as np
 
 from ... import obs
+from .. import resolve_use_kernel
 from ...core.sparse.sell import SellCS
+from ..segments import row_aligned_segments
 from .kernel import sell_spmm
 from .ref import sell_spmm_ref
+
+
+def _segments(chunk_slice, num_slices: int) -> tuple:
+    """SMEM segments of whole slices over the chunk list."""
+    counts = np.bincount(np.asarray(chunk_slice, np.int64),
+                         minlength=num_slices)
+    return row_aligned_segments(np.concatenate([[0], np.cumsum(counts)]),
+                                n_arrays=1)
 
 
 class SellOperator:
@@ -24,16 +35,12 @@ class SellOperator:
         self.sigma = host.sigma
         self.w = host.w
         self.num_slices = host.num_slices
-        # pad x to a lane multiple (gather indices all < n, so padding is
-        # never read; it only keeps the VMEM buffer tile-aligned)
-        self.n_pad = ((host.shape[1] + 127) // 128) * 128
         self.chunk_vals = jnp.asarray(host.chunk_vals, dtype=dtype)
         self.chunk_cols = jnp.asarray(host.chunk_cols, dtype=jnp.int32)
         self.chunk_slice = jnp.asarray(host.chunk_slice, dtype=jnp.int32)
         self.inv_perm = jnp.asarray(host.inv_perm, dtype=jnp.int32)
-        if use_kernel == "auto":
-            use_kernel = "pallas" if jax.default_backend() == "tpu" else "ref"
-        self.use_kernel = use_kernel
+        self.segments = _segments(host.chunk_slice, self.num_slices)
+        self.use_kernel = resolve_use_kernel(use_kernel, "sell")
 
     def __call__(self, x: jax.Array) -> jax.Array:
         """x: [n] or [n, nv] -> y: [m] or [m, nv]."""
@@ -42,18 +49,15 @@ class SellOperator:
             squeeze = x.ndim == 1
             if squeeze:
                 x = x[:, None]
-            n, nv = x.shape
-            xp = jnp.pad(x, ((0, self.n_pad - n), (0, 0)))
-            if self.use_kernel == "pallas":
+            nv = x.shape[1]
+            if self.use_kernel in ("pallas", "interpret"):
                 y = sell_spmm(self.chunk_vals, self.chunk_cols,
-                              self.chunk_slice, xp, self.num_slices)
-            elif self.use_kernel == "interpret":
-                y = sell_spmm(self.chunk_vals, self.chunk_cols,
-                              self.chunk_slice, xp, self.num_slices,
-                              interpret=True)
+                              self.chunk_slice, x, self.num_slices, kb=nv,
+                              segments=self.segments,
+                              interpret=(self.use_kernel == "interpret"))
             else:
                 y = sell_spmm_ref(self.chunk_vals, self.chunk_cols,
-                                  self.chunk_slice, xp, self.num_slices)
+                                  self.chunk_slice, x, self.num_slices)
             # y is in slice order; inv_perm[r] = slice position of
             # original row r
             y = y.reshape(-1, nv)[self.inv_perm]
@@ -73,7 +77,7 @@ class SellOperator:
     def state(self):
         meta = {"shape": list(self.shape), "c": self.c, "sigma": self.sigma,
                 "w": self.w, "num_slices": self.num_slices,
-                "n_pad": self.n_pad, "use_kernel": self.use_kernel}
+                "use_kernel": self.use_kernel}
         arrays = {"chunk_vals": np.asarray(self.chunk_vals),
                   "chunk_cols": np.asarray(self.chunk_cols),
                   "chunk_slice": np.asarray(self.chunk_slice),
@@ -85,12 +89,13 @@ class SellOperator:
         op = object.__new__(cls)
         op.shape = tuple(meta["shape"])
         op.c, op.sigma, op.w = meta["c"], meta["sigma"], meta["w"]
-        op.num_slices, op.n_pad = meta["num_slices"], meta["n_pad"]
+        op.num_slices = meta["num_slices"]
         op.use_kernel = meta["use_kernel"]
         op.chunk_vals = jnp.asarray(arrays["chunk_vals"], dtype=dtype)
         op.chunk_cols = jnp.asarray(arrays["chunk_cols"])
         op.chunk_slice = jnp.asarray(arrays["chunk_slice"])
         op.inv_perm = jnp.asarray(arrays["inv_perm"])
+        op.segments = _segments(arrays["chunk_slice"], op.num_slices)
         return op
 
     @property

@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..core.spmv import ref
 from . import hlo_cost
@@ -57,8 +56,8 @@ def lower_1d(mesh: Mesh):
                 return y.reshape(-1)[:panel_n], None
             xf, _ = jax.lax.scan(one, xl[0], None, length=ITERS)
             return xf[None]
-        f = shard_map(body, mesh=mesh, in_specs=(P(axes), P(axes), P(axes)),
-                      out_specs=P(axes))
+        f = jax.shard_map(body, mesh=mesh, in_specs=(P(axes), P(axes), P(axes)),
+                          out_specs=P(axes))
         return f(blocks, cols, x)
 
     blocks = jax.ShapeDtypeStruct((n_dev, nbr_l, K_1D, BM, BN), jnp.float32,
@@ -90,10 +89,10 @@ def lower_2d(mesh: Mesh):
             x0 = jax.lax.pcast(xl[0], ("data",), to="varying")
             xf, _ = jax.lax.scan(one, x0, None, length=ITERS)
             return xf[None]
-        f = shard_map(body, mesh=mesh,
-                      in_specs=(P("data", "model"), P("data", "model"),
-                                P("model")),
-                      out_specs=P("model"), check_rep=False)
+        f = jax.shard_map(body, mesh=mesh,
+                          in_specs=(P("data", "model"), P("data", "model"),
+                                    P("model")),
+                          out_specs=P("model"), check_vma=False)
         return f(blocks, cols, x_segs)
 
     blocks = jax.ShapeDtypeStruct((d, m, nbr_l, k2, BM, BN), jnp.float32,
@@ -128,8 +127,8 @@ def lower_halo(mesh: Mesh, halo: int = 128):
                 return y.reshape(-1)[:panel_n], None
             xf, _ = jax.lax.scan(one, xl[0], None, length=ITERS)
             return xf[None]
-        f = shard_map(body, mesh=mesh, in_specs=(P(axes), P(axes), P(axes)),
-                      out_specs=P(axes))
+        f = jax.shard_map(body, mesh=mesh, in_specs=(P(axes), P(axes), P(axes)),
+                          out_specs=P(axes))
         return f(blocks, cols, x)
 
     k_halo = 2  # banded window spans <= 2 column blocks per block row
@@ -561,6 +560,9 @@ def main():
                          ".jsonl -> raw event log, anything else -> "
                          "Chrome-trace JSON (load in ui.perfetto.dev)")
     args = ap.parse_args()
+    from .. import compile_cache
+
+    compile_cache.enable()
     if not args.trace:
         _dispatch(ap, args)
         return

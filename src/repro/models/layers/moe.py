@@ -154,7 +154,6 @@ def moe_layer(params, x, moe_cfg, mesh=None, ep_axis="model",
         return _moe_body(params, x, moe_cfg=moe_cfg, ep_axis=None, ep_size=1)
 
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     from ...distributed import sharding as _SH
 
@@ -179,9 +178,9 @@ def moe_layer(params, x, moe_cfg, mesh=None, ep_axis="model",
                    for k, v in metrics.items()}
         return y, metrics
 
-    f = shard_map(body, mesh=mesh,
-                  in_specs=(wspec, xspec),
-                  out_specs=(xspec, {"aux_loss": P(), "router_li": P(),
-                                     "drop_frac": P()}),
-                  check_rep=False)
+    f = jax.shard_map(body, mesh=mesh,
+                      in_specs=(wspec, xspec),
+                      out_specs=(xspec, {"aux_loss": P(), "router_li": P(),
+                                         "drop_frac": P()}),
+                      check_vma=False)
     return f(params, x)

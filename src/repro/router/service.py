@@ -7,8 +7,9 @@ end out: a RoutingTable places each registered key onto one mesh of a
 fleet (placement.py policies — bin-pack by estimated bytes, per-device
 nnz balance, comm-model-aware co-placement), each mesh is served by its
 own `_MeshService` (an SpmvService subclass whose budget bounds EVERY
-device via per-device operator accounting), and requests dispatch through
-a `router.dispatch` span to the owning mesh.
+device via per-device operator accounting) on its own slice of the
+process's devices, and requests dispatch through a `router.dispatch`
+span to the owning mesh.
 
 Updates are where the router earns its subclass: a plain service refuses
 sharded-key updates (`RoutedElsewhere`), while `_MeshService` flips
@@ -36,6 +37,8 @@ from __future__ import annotations
 
 import threading
 from typing import Dict, List, Optional
+
+import numpy as np
 
 from .. import obs
 from ..core.sparse.csr import CSRMatrix
@@ -69,6 +72,19 @@ class _MeshService(SpmvService):
         return [totals.get(d, 0) for d in range(ndev)]
 
 
+def _device_slices(sizes: List[int]) -> list:
+    """Consecutive, disjoint device slices, one per mesh, when the
+    process has devices for the whole fleet; otherwise None for every
+    mesh (each runs on the first devices, or simulated)."""
+    import jax
+
+    devs = jax.devices()
+    if sum(sizes) > len(devs):
+        return [None] * len(sizes)
+    starts = np.cumsum([0] + list(sizes))
+    return [tuple(devs[a:b]) for a, b in zip(starts[:-1], starts[1:])]
+
+
 class RoutedSpmvService:
     """Route keys across meshes; serve each from its own SpmvService.
 
@@ -95,14 +111,17 @@ class RoutedSpmvService:
         self.table = RoutingTable(meshes, policy=policy)
         service_kw.pop("topology", None)
         service_kw.pop("memory_budget_bytes", None)
+        service_kw.pop("devices", None)
         self._services: Dict[str, _MeshService] = {}
-        for spec in self.table.meshes:
+        slices = _device_slices([s.topology.devices
+                                 for s in self.table.meshes])
+        for spec, devices in zip(self.table.meshes, slices):
             budget = (None if spec.budget_per_device is None
                       else int(spec.budget_per_device)
                       * spec.topology.devices)
             self._services[spec.name] = _MeshService(
-                topology=spec.topology, memory_budget_bytes=budget,
-                **service_kw)
+                devices=devices, topology=spec.topology,
+                memory_budget_bytes=budget, **service_kw)
         self._mats: Dict[str, CSRMatrix] = {}
         self._lock = threading.Lock()
         self._closed = False

@@ -202,7 +202,7 @@ class SpmvService:
                  window_ms: float = 2.0, use_kernel: str = "auto",
                  dtype=None, cache: bool = True, probe: bool = False,
                  max_queue: int = 1024, reorder: str = "baseline",
-                 topology=None, partition: str = "auto",
+                 topology=None, partition: str = "auto", devices=None,
                  memory_budget_bytes: Optional[int] = None,
                  overload: str = "reject",
                  max_queue_global: Optional[int] = None,
@@ -222,6 +222,9 @@ class SpmvService:
         self.reorder = reorder
         self.topology = topology
         self.partition = partition
+        # the mesh devices sharded operators are built on (None: the
+        # process's first ones); the router gives each mesh its own
+        self.devices = None if devices is None else tuple(devices)
         self.max_batch = int(max_batch)
         self.max_queue = int(max_queue)
         self.max_queue_global = (None if max_queue_global is None
@@ -425,7 +428,8 @@ class SpmvService:
         if (dirty and hint is not None
                 and hint[0] == plan_mod.structure_key(mat)
                 and hint[1] == scheme):
-            op = hint[2].rebuild(mat, use_kernel=self.use_kernel)
+            op = hint[2].rebuild(mat, use_kernel=self.use_kernel,
+                                 devices=self.devices)
             return op, hint[2], op.build_info
         from ..api import SpmvProblem, plan as make_plan
 
@@ -434,7 +438,7 @@ class SpmvService:
                         hints={"use_kernel": self.use_kernel}),
             reorder=scheme, engine=self.engine, probe=self.probe,
             cache=self.cache, topology=topology, partition=self.partition)
-        op = pl.build(cache=self.cache)
+        op = pl.build(cache=self.cache, devices=self.devices)
         return op, pl, op.build_info
 
     # -- dynamic matrices --------------------------------------------------
@@ -477,7 +481,8 @@ class SpmvService:
         if hint is None or hint[1] != scheme:
             return          # no operator planned yet: first dispatch plans
         # rebuild OUTSIDE the lock — the old operator keeps serving
-        op = hint[2].rebuild(new_mat, use_kernel=self.use_kernel)
+        op = hint[2].rebuild(new_mat, use_kernel=self.use_kernel,
+                             devices=self.devices)
         nb = self._op_nbytes(op)
         with self._cv:
             if self._gen[key] == gen and not self._stop:
@@ -584,8 +589,11 @@ class SpmvService:
                         try:
                             pl = hint[2].apply_delta(delta)
                             op = (pl.rebuild(mat,
-                                             use_kernel=self.use_kernel)
-                                  if dirty else pl.build(cache=self.cache))
+                                             use_kernel=self.use_kernel,
+                                             devices=self.devices)
+                                  if dirty else
+                                  pl.build(cache=self.cache,
+                                           devices=self.devices))
                             info = op.build_info
                         except delta_mod.DeltaTooLarge:
                             op = pl = info = None

@@ -14,6 +14,7 @@ from repro.kernels.bcsr_spmv.ref import bcsr_spmm_ref
 from repro.kernels.bell_spmv.kernel import bell_spmm
 from repro.kernels.bell_spmv.ops import BellOperator
 from repro.kernels.bell_spmv.ref import bell_spmm_ref
+from repro.kernels.segments import row_aligned_segments
 from repro.matrices import generators as G
 
 
@@ -36,7 +37,7 @@ def test_bell_kernel_shape_sweep(kind, bm, bn, nv):
     x2d = jnp.asarray(rng.standard_normal((ncb, bn, nv)), jnp.float32)
     blocks = jnp.asarray(host.blocks, jnp.float32)
     cols = jnp.asarray(host.block_cols)
-    got = bell_spmm(blocks, cols, x2d, interpret=True)
+    got = bell_spmm(blocks, cols.reshape(-1), x2d, interpret=True)
     want = bell_spmm_ref(blocks, cols, x2d)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
@@ -51,7 +52,8 @@ def test_bcsr_kernel_shape_sweep(kind, bm, bn):
     x2d = jnp.asarray(rng.standard_normal((ncb, bn, 1)), jnp.float32)
     blocks = jnp.asarray(host.blocks, jnp.float32)
     got = bcsr_spmm(blocks, jnp.asarray(host.block_rows), jnp.asarray(host.block_cols),
-                    x2d, host.num_block_rows, interpret=True)
+                    x2d, host.num_block_rows,
+                    row_aligned_segments(host.block_rowptr), interpret=True)
     want = bcsr_spmm_ref(blocks, jnp.asarray(host.block_rows),
                          jnp.asarray(host.block_cols), x2d, host.num_block_rows)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
@@ -66,7 +68,8 @@ def test_bell_kernel_dtypes(dtype, tol):
     x2d = jnp.asarray(rng.standard_normal((ncb, 8, 1)), dtype)
     blocks = jnp.asarray(host.blocks, dtype)
     cols = jnp.asarray(host.block_cols)
-    got = np.asarray(bell_spmm(blocks, cols, x2d, interpret=True), np.float64)
+    got = np.asarray(bell_spmm(blocks, cols.reshape(-1), x2d, interpret=True),
+                     np.float64)
     want = np.asarray(bell_spmm_ref(blocks, cols, x2d), np.float64)
     scale = np.abs(want).max() + 1e-9
     assert np.abs(got - want).max() / scale < tol
@@ -93,3 +96,30 @@ def test_property_bell_vs_numpy(m, seed):
     got = np.asarray(op(jnp.asarray(x, jnp.float32)))
     want = mat.spmv(x)
     assert np.abs(got - want).max() < 1e-4 * (np.abs(want).max() + 1)
+
+
+@pytest.mark.parametrize("engine", ["bell", "bcsr", "sell"])
+def test_smem_segments_match_oracle(engine, monkeypatch):
+    """With a tiny SMEM index budget every kernel runs as several
+    pallas_calls (one per segment of whole rows); the concatenated rows
+    must equal the one-call result."""
+    from repro.core.spmv.ops import make_engine
+    from repro.kernels import segments
+
+    monkeypatch.setattr(segments, "SMEM_INDEX_BYTES", 32)
+    mat = G.stencil_2d(13, seed=4)
+    op = make_engine(mat, engine, use_kernel="interpret")
+    if engine == "bell":
+        nbr, kk = op.blocks.shape[:2]
+        assert len(segments.row_aligned_segments(
+            np.arange(nbr + 1) * kk, n_arrays=1)) > 1
+    else:
+        assert len(op.segments) > 1
+    rng = np.random.default_rng(5)
+    x = rng.standard_normal(mat.n).astype(np.float32)
+    xk = rng.standard_normal((mat.n, 3)).astype(np.float32)
+    np.testing.assert_allclose(np.asarray(op(jnp.asarray(x))), mat.spmv(x),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(op.matmul(jnp.asarray(xk))),
+                               np.stack([mat.spmv(c) for c in xk.T], 1),
+                               rtol=1e-5, atol=1e-5)

@@ -62,10 +62,13 @@ def test_span_nesting_single_thread():
 
 
 def test_span_nesting_across_threads():
+    # the workers overlap, so no thread id is reused by a later worker
+    barrier = threading.Barrier(3)
+
     def worker(i):
         with obs.span("worker", idx=i):
             with obs.span("child", idx=i):
-                time.sleep(0.001)
+                barrier.wait(timeout=30)
 
     with obs.tracing() as buf:
         with obs.span("main_root"):

@@ -23,6 +23,7 @@ import warnings
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.api import (PARTITIONER_REGISTRY, Plan, ShardedOperator,
@@ -279,6 +280,10 @@ def test_sharded_plan_rejects_bad_requests(stores):
     with pytest.raises(KeyError):        # unknown partitioner
         plan(SpmvProblem(mat), reorder="baseline",
              topology=Topology(devices=4), partition="nope")
+    pl = plan(SpmvProblem(mat), reorder="baseline",
+              topology=Topology(devices=4))
+    with pytest.raises(ValueError, match="1 devices for a 4-device mesh"):
+        pl.build(devices=jax.devices()[:1])
 
 
 def test_cg_through_sharded_operator(stores):
