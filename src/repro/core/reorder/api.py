@@ -126,29 +126,41 @@ def _content_key(mat: CSRMatrix, scheme: str, seed: int) -> str:
 
 
 def reorder(mat: CSRMatrix, scheme: str, seed: int = 0, cache: bool = True) -> np.ndarray:
+    return reorder_timed(mat, scheme, seed, cache)[0]
+
+
+def reorder_timed(mat: CSRMatrix, scheme: str, seed: int = 0,
+                  cache: bool = True) -> tuple:
+    """(reorder(...), milliseconds of its `plan.reorder` span), the span
+    timed whether or not tracing is on: the planner's `reorder_ms`."""
     fn = get_scheme(scheme).fn
-    with obs.span("plan.reorder", scheme=scheme, seed=int(seed),
-                  shape=str(tuple(mat.shape))) as sp:
-        if not cache:
-            return fn(mat, seed)
-        cache_dir = _cache_dir()
-        os.makedirs(cache_dir, exist_ok=True)
-        path = os.path.join(cache_dir,
-                            _content_key(mat, scheme, seed) + ".npy")
-        if os.path.exists(path):
-            obs.counter("reorder_cache.hits").inc()
-            sp.set(cache_hit=True)
-            return np.load(path)
-        obs.counter("reorder_cache.misses").inc()
-        sp.set(cache_hit=False)
-        perm = fn(mat, seed)
-        # write-then-rename (opcache.py's tmp-name convention: pid AND
-        # thread id) so a concurrent run never reads a torn .npy
-        tmp = f"{path}.{os.getpid()}.{threading.get_ident()}.tmp"
-        with open(tmp, "wb") as f:
-            np.save(f, perm)
-        os.replace(tmp, path)
-        return perm
+    with obs.timed("plan.reorder", scheme=scheme, seed=int(seed),
+                   shape=str(tuple(mat.shape))) as sp:
+        perm = _reorder(mat, fn, scheme, seed, cache, sp)
+    return perm, sp.ms
+
+
+def _reorder(mat: CSRMatrix, fn, scheme: str, seed: int, cache: bool,
+             sp) -> np.ndarray:
+    if not cache:
+        return fn(mat, seed)
+    cache_dir = _cache_dir()
+    os.makedirs(cache_dir, exist_ok=True)
+    path = os.path.join(cache_dir, _content_key(mat, scheme, seed) + ".npy")
+    if os.path.exists(path):
+        obs.counter("reorder_cache.hits").inc()
+        sp.set(cache_hit=True)
+        return np.load(path)
+    obs.counter("reorder_cache.misses").inc()
+    sp.set(cache_hit=False)
+    perm = fn(mat, seed)
+    # write-then-rename (opcache.py's tmp-name convention: pid AND
+    # thread id) so a concurrent run never reads a torn .npy
+    tmp = f"{path}.{os.getpid()}.{threading.get_ident()}.tmp"
+    with open(tmp, "wb") as f:
+        np.save(f, perm)
+    os.replace(tmp, path)
+    return perm
 
 
 def apply_scheme(mat: CSRMatrix, scheme: str, seed: int = 0, cache: bool = True) -> CSRMatrix:

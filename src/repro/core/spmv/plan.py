@@ -239,21 +239,30 @@ class Operator:
         return self.inner
 
     def __call__(self, x, permuted: bool = False):
-        import jax.numpy as jnp
-
-        if self._perm is None or permuted:
-            return self.inner(x)
-        xr = jnp.take(x, self._perm, axis=0)
-        return jnp.take(self.inner(xr), self._iperm, axis=0)
+        return self._apply(self.inner, x, permuted)
 
     def matmul(self, x, permuted: bool = False):
         """x: [n, k] -> y: [m, k], original index space unless permuted."""
+        return self._apply(self.inner.matmul, x, permuted)
+
+    def _apply(self, engine, x, permuted: bool):
+        """One application under the device scopes `spmv` (all of it),
+        `spmv.permute` (the two permutation gathers) and `spmv.engine` (the
+        engine's call). A scope reaches the ops traced inside it, so they
+        name device time where this runs under jit."""
+        import jax
         import jax.numpy as jnp
 
-        if self._perm is None or permuted:
-            return self.inner.matmul(x)
-        xr = jnp.take(x, self._perm, axis=0)
-        return jnp.take(self.inner.matmul(xr), self._iperm, axis=0)
+        with jax.named_scope("spmv"):
+            if self._perm is None or permuted:
+                with jax.named_scope("spmv.engine"):
+                    return engine(x)
+            with jax.named_scope("spmv.permute"):
+                xr = jnp.take(x, self._perm, axis=0)
+            with jax.named_scope("spmv.engine"):
+                yr = engine(xr)
+            with jax.named_scope("spmv.permute"):
+                return jnp.take(yr, self._iperm, axis=0)
 
 
 @dataclasses.dataclass
@@ -433,7 +442,8 @@ class Plan:
                 raise ValueError("plan has no attached matrix; pass mat= to "
                                  "Plan.load or use plan(problem, ...)")
             self._rmat = (self._mat if self.perm is None
-                          else self._mat.permute(self.perm))
+                          else _permute(self._mat, self.perm,
+                                        scheme=self.scheme)[0])
         return self._rmat
 
     def _restore_operator(self, dtype):
@@ -537,7 +547,8 @@ class Plan:
         with obs.span("plan.rebuild", key=self.key,
                       engine=self.tune.engine,
                       sharded=self.topology is not None):
-            rmat = mat if self.perm is None else mat.permute(self.perm)
+            rmat = (mat if self.perm is None
+                    else _permute(mat, self.perm, scheme=self.scheme)[0])
             t0 = time.perf_counter()
             if self.topology is not None:
                 from . import distributed
@@ -636,6 +647,30 @@ class Plan:
         return op
 
 
+def _permute(mat: CSRMatrix, perm, **attrs) -> tuple:
+    """(mat.permute(perm), its milliseconds), under a `plan.permute` span."""
+    with obs.timed("plan.permute", shape=str(tuple(mat.shape)),
+                   nnz=int(mat.nnz), **attrs) as sp:
+        rmat = mat.permute(perm)
+    return rmat, sp.ms
+
+
+def _reorder_scheme(mat: CSRMatrix, scheme: str, seed: int,
+                    cache: bool) -> tuple:
+    """(perm, reordered matrix, ms): the scheme's `plan.reorder` span and
+    its `plan.permute` span, the milliseconds being theirs. `baseline` is
+    the identity and runs neither."""
+    from ..reorder import api as reorder_api
+
+    if scheme == "baseline":
+        return None, mat, 0.0
+    perm, ms = reorder_api.reorder_timed(mat, scheme, seed, cache=cache)
+    if perm is None:
+        return None, mat, ms
+    rmat, pms = _permute(mat, perm, scheme=scheme)
+    return perm, rmat, ms + pms
+
+
 def _operator_registry() -> dict:
     """Operator classes speaking the state()/from_state() protocol
     (opcache.py's set). Imported lazily: kernels pull in pallas."""
@@ -723,7 +758,7 @@ def _plan_decide(problem: SpmvProblem, reorder: str = "auto",
               to search the auto-candidate partitioners.
     """
     from . import ops  # noqa: F401 — ensure built-in engines are registered
-    from ..reorder import api as reorder_api
+    from ..reorder import api  # noqa: F401 — and built-in schemes
 
     if probe not in tune_mod.PROBE_MODES:
         raise ValueError(
@@ -784,12 +819,8 @@ def _plan_decide(problem: SpmvProblem, reorder: str = "auto",
     best = None                       # (cost, scheme, perm, rmat, tuneplan)
     scheme_costs: dict = {}
     for s in schemes:
-        t0 = time.perf_counter()
-        perm = (None if s == "baseline"
-                else reorder_api.reorder(mat, s, seed, cache=cache))
-        rmat = mat if perm is None else mat.permute(perm)
-        reorder_ms += (time.perf_counter() - t0) * 1e3
-        t0 = time.perf_counter()
+        perm, rmat, ms = _reorder_scheme(mat, s, seed, cache)
+        reorder_ms += ms
         if engine == "auto":
             # single explicit scheme: probe directly (the legacy tune path);
             # multi-scheme search stays model-based until a winner exists
@@ -797,19 +828,24 @@ def _plan_decide(problem: SpmvProblem, reorder: str = "auto",
                                probe=(probe if len(schemes) == 1 else False),
                                use_kernel=use_kernel, k=k)
             cost = tp.cost_bytes
+            tune_ms += tp.tune_ms
         else:
-            feat = tune_mod.matrix_features(rmat)
-            sp = None
-            if engine == "sell":
-                from ..sparse.sell import sell_padded_nnz
+            with obs.timed("plan.tune", shape=str(tuple(rmat.shape)),
+                           nnz=int(rmat.nnz), probe="False", k=int(k),
+                           engine=engine, source="fixed") as tsp:
+                feat = tune_mod.matrix_features(rmat)
+                sp = None
+                if engine == "sell":
+                    from ..sparse.sell import sell_padded_nnz
 
-                c, w = block_shape
-                sg = 8 * c if sell_sigma is None else sell_sigma
-                sp = sell_padded_nnz(rmat, c, sg, w)
-            cost = tune_mod.candidate_cost(feat, engine, block_shape,
-                                           sell_sigma, sp, k=k)
-            tp = tune_mod.fixed_plan(engine, block_shape, sell_sigma, k=k)
-        tune_ms += (time.perf_counter() - t0) * 1e3
+                    c, w = block_shape
+                    sg = 8 * c if sell_sigma is None else sell_sigma
+                    sp = sell_padded_nnz(rmat, c, sg, w)
+                cost = tune_mod.candidate_cost(feat, engine, block_shape,
+                                               sell_sigma, sp, k=k)
+                tp = tune_mod.fixed_plan(engine, block_shape, sell_sigma,
+                                         k=k)
+            tune_ms += tsp.ms
         scheme_costs[s] = float(cost)
         if best is None or cost < best[0]:
             best = (cost, s, perm, rmat, tp)
@@ -819,9 +855,8 @@ def _plan_decide(problem: SpmvProblem, reorder: str = "auto",
         # engine choice on the winner only (probing every scheme would
         # time the planner, not the SpMV) — in the caller's probe mode,
         # so "learned"/"exhaustive" reach the winner's tune too
-        t0 = time.perf_counter()
         tp = tune_mod.tune(rmat, probe=probe, use_kernel=use_kernel, k=k)
-        tune_ms += (time.perf_counter() - t0) * 1e3
+        tune_ms += tp.tune_ms
 
     pl = Plan(scheme=scheme, seed=seed, engine_request=engine, tune=tp,
               k=k, dtype_name=dtype_name, probe=probe, use_kernel=use_kernel,
@@ -848,8 +883,6 @@ def _plan_sharded(problem: SpmvProblem, reorder: str, engine: str,
     imbalance / devices) + collective bytes (topology.comm_model). The
     winner's composed permutation (scheme ∘ partitioner grouping) and
     panel split ride on the Plan, so build() needs no re-decision."""
-    from ..reorder import api as reorder_api
-
     mat = problem.mat
     k = max(int(problem.k), 1)
     dtype_name = problem.dtype_name()
@@ -860,46 +893,48 @@ def _plan_sharded(problem: SpmvProblem, reorder: str, engine: str,
     scheme_costs: dict = {}
     partition_costs: dict = {}
     for s in schemes:
-        t0 = time.perf_counter()
-        perm = (None if s == "baseline"
-                else reorder_api.reorder(mat, s, seed, cache=cache))
-        rmat = mat if perm is None else mat.permute(perm)
-        reorder_ms += (time.perf_counter() - t0) * 1e3
+        perm, rmat, ms = _reorder_scheme(mat, s, seed, cache)
+        reorder_ms += ms
         best_s = None
         feat_rmat = None     # non-reordering partitioners all score the
         # scheme's own rmat: one feature scan serves them all
         for pname in partitioners:
             cname, pfn = partition_mod.resolve_partitioner(pname)
-            t0 = time.perf_counter()
-            perm2, starts = pfn(rmat, topo.row_devices, seed)
-            rmat2 = rmat if perm2 is None else rmat.permute(perm2)
+            with obs.timed("plan.partition", partitioner=cname,
+                           scheme=s) as psp:
+                perm2, starts = pfn(rmat, topo.row_devices, seed)
+            reorder_ms += psp.ms
+            rmat2 = rmat
             if perm2 is None:
                 perm_total = perm
             else:
+                rmat2, ms = _permute(rmat, perm2, scheme=s, partitioner=cname)
+                reorder_ms += ms
                 perm_total = (np.asarray(perm2, np.int64) if perm is None
                               else np.asarray(perm, np.int64)[perm2])
-            reorder_ms += (time.perf_counter() - t0) * 1e3
-            t0 = time.perf_counter()
-            if rmat2 is rmat:
-                if feat_rmat is None:
-                    feat_rmat = tune_mod.matrix_features(rmat)
-                feat = feat_rmat
-            else:
-                feat = tune_mod.matrix_features(rmat2)
-            comm = topology_mod.comm_model(rmat2, starts, topo, dsize, k,
-                                           block_shape)
-            for eng in engines:
-                compute = tune_mod.candidate_cost(feat, eng, block_shape,
-                                                  None, None, k=k)
-                cost = (compute * comm["li"] / topo.devices
-                        + comm["bytes_per_spmv"])
-                partition_costs[f"{s}+{cname}+{eng}"] = float(cost)
-                if best is None or cost < best[0]:
-                    best = (cost, s, perm_total, rmat2, starts, cname, eng,
-                            float(compute), comm)
-                if best_s is None or cost < best_s:
-                    best_s = float(cost)
-            tune_ms += (time.perf_counter() - t0) * 1e3
+            with obs.timed("plan.tune", shape=str(tuple(rmat2.shape)),
+                           nnz=int(rmat2.nnz), k=int(k), partitioner=cname,
+                           sharded=True) as tsp:
+                if rmat2 is rmat:
+                    if feat_rmat is None:
+                        feat_rmat = tune_mod.matrix_features(rmat)
+                    feat = feat_rmat
+                else:
+                    feat = tune_mod.matrix_features(rmat2)
+                comm = topology_mod.comm_model(rmat2, starts, topo, dsize, k,
+                                               block_shape)
+                for eng in engines:
+                    compute = tune_mod.candidate_cost(feat, eng, block_shape,
+                                                      None, None, k=k)
+                    cost = (compute * comm["li"] / topo.devices
+                            + comm["bytes_per_spmv"])
+                    partition_costs[f"{s}+{cname}+{eng}"] = float(cost)
+                    if best is None or cost < best[0]:
+                        best = (cost, s, perm_total, rmat2, starts, cname, eng,
+                                float(compute), comm)
+                    if best_s is None or cost < best_s:
+                        best_s = float(cost)
+            tune_ms += tsp.ms
         scheme_costs[s] = best_s
     _, scheme, perm_total, rmat2, starts, pname, eng, compute, comm = best
     tp = TunePlan(engine=eng, block_shape=tuple(block_shape),

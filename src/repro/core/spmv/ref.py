@@ -14,6 +14,13 @@ import jax.numpy as jnp
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
+def gather(x: jax.Array, idx: jax.Array) -> jax.Array:
+    """x[idx]: an engine's gather of x, under the `spmv.gather` scope so a
+    device trace names its ops."""
+    with jax.named_scope("spmv.gather"):
+        return x[idx]
+
+
 def spmv_dense(a_dense: jax.Array, x: jax.Array) -> jax.Array:
     return a_dense @ x
 
@@ -24,7 +31,7 @@ def spmv_csr(row_ids: jax.Array, cols: jax.Array, vals: jax.Array,
 
     row_ids: int32[nnz] (row of each stored element, nondecreasing)
     """
-    prod = vals * x[cols]
+    prod = vals * gather(x, cols)
     return jax.ops.segment_sum(prod, row_ids, num_segments=m,
                                indices_are_sorted=True)
 
@@ -37,7 +44,7 @@ def spmm_csr(row_ids: jax.Array, cols: jax.Array, vals: jax.Array,
     rides along each gathered element, so the matrix stream (vals/cols/
     row_ids) is paid once for all k vectors.
     """
-    prod = vals[:, None] * x[cols]                   # [nnz, k]
+    prod = vals[:, None] * gather(x, cols)                   # [nnz, k]
     return jax.ops.segment_sum(prod, row_ids, num_segments=m,
                                indices_are_sorted=True)
 
@@ -49,7 +56,7 @@ def spmv_ell(ell_cols: jax.Array, ell_vals: jax.Array, x: jax.Array) -> jax.Arra
     Slot-major keeps the long axis minor: on a TPU an [m, K] array pads
     K to 128 lanes, and its scalar gather took the v5e compiler ~100 s at
     a million rows where [K, m] takes about one."""
-    return jnp.sum(ell_vals * x[ell_cols], axis=0)
+    return jnp.sum(ell_vals * gather(x, ell_cols), axis=0)
 
 
 def spmm_ell(ell_cols: jax.Array, ell_vals: jax.Array, x: jax.Array) -> jax.Array:
@@ -66,7 +73,7 @@ def spmm_ell(ell_cols: jax.Array, ell_vals: jax.Array, x: jax.Array) -> jax.Arra
     """
     out_dtype = jnp.promote_types(ell_vals.dtype, x.dtype)   # == __call__'s
     acc = jnp.promote_types(out_dtype, jnp.float32)
-    gathered = x[ell_cols]                           # [K, m, k]
+    gathered = gather(x, ell_cols)                         # [K, m, k]
     return jnp.einsum("jm,jmv->mv", ell_vals, gathered,
                       preferred_element_type=acc,
                       precision=HIGHEST).astype(out_dtype)
@@ -79,7 +86,7 @@ def spmv_bell(blocks: jax.Array, block_cols: jax.Array, x2d: jax.Array) -> jax.A
     Padding blocks are all-zero so their contribution vanishes regardless of
     block_cols padding value.
     """
-    gathered = x2d[block_cols]                       # [nbr, K, bn, nv]
+    gathered = gather(x2d, block_cols)              # [nbr, K, bn, nv]
     return jnp.einsum("rkij,rkjv->riv", blocks, gathered,
                       preferred_element_type=jnp.float32,
                       precision=HIGHEST).astype(x2d.dtype)
@@ -94,7 +101,7 @@ def spmv_sell(chunk_vals: jax.Array, chunk_cols: jax.Array,
 
     Padding slots have val 0 (col 0), so they add exactly 0.
     """
-    gathered = x[chunk_cols]                         # [T, C, W, nv]
+    gathered = gather(x, chunk_cols)                       # [T, C, W, nv]
     partial = jnp.einsum("tcw,tcwv->tcv", chunk_vals, gathered,
                          preferred_element_type=jnp.float32,
                          precision=HIGHEST)
@@ -106,7 +113,7 @@ def spmv_sell(chunk_vals: jax.Array, chunk_cols: jax.Array,
 def spmv_bcsr(blocks: jax.Array, block_rows: jax.Array, block_cols: jax.Array,
               x2d: jax.Array, num_block_rows: int) -> jax.Array:
     """BCSR: blocks [T, bm, bn], block_rows/cols [T]. Returns [nbr, bm, nv]."""
-    gathered = x2d[block_cols]                       # [T, bn, nv]
+    gathered = gather(x2d, block_cols)              # [T, bn, nv]
     partial = jnp.einsum("tij,tjv->tiv", blocks, gathered,
                          preferred_element_type=jnp.float32,
                          precision=HIGHEST)

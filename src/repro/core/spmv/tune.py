@@ -43,7 +43,6 @@ tuned operators across processes lives in opcache.py; the joint
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Optional
 
 import numpy as np
@@ -285,9 +284,11 @@ def tune(mat: CSRMatrix, probe=False, dtype=None,
     """
     if probe not in PROBE_MODES:
         raise ValueError(f"probe must be one of {PROBE_MODES}, got {probe!r}")
-    with obs.span("plan.tune", shape=str(tuple(mat.shape)),
-                  nnz=int(mat.nnz), probe=str(probe), k=int(k)) as _sp:
-        return _tune_impl(mat, probe, dtype, use_kernel, k, _sp, advisor)
+    with obs.timed("plan.tune", shape=str(tuple(mat.shape)),
+                   nnz=int(mat.nnz), probe=str(probe), k=int(k)) as _sp:
+        tp = _tune_impl(mat, probe, dtype, use_kernel, k, _sp, advisor)
+    # tune_ms is the span's own interval
+    return dataclasses.replace(tp, tune_ms=_sp.ms)
 
 
 def _probe_set(probe, ranked, feat, advisor):
@@ -310,7 +311,6 @@ def _probe_set(probe, ranked, feat, advisor):
 
 def _tune_impl(mat: CSRMatrix, probe, dtype, use_kernel: str, k: int,
                _sp, advisor=None) -> TunePlan:
-    t0 = time.perf_counter()
     k = max(int(k), 1)
     feat = matrix_features(mat)
     cands = enumerate_candidates(mat, feat)
@@ -363,8 +363,7 @@ def _tune_impl(mat: CSRMatrix, probe, dtype, use_kernel: str, k: int,
     return TunePlan(engine=best["engine"], block_shape=best["block_shape"],
                     sell_sigma=best["sigma"], cost_bytes=costs[lab],
                     costs=costs, features=feat, source=source,
-                    probe_ms=probe_ms,
-                    tune_ms=(time.perf_counter() - t0) * 1e3, k=k,
+                    probe_ms=probe_ms, k=k,
                     advisor=adv_info)
 
 

@@ -92,7 +92,8 @@ def sell_spmm(chunk_vals: jax.Array, chunk_cols: jax.Array,
     # accumulate at >= the operator dtype (f32 floor): an f64 operator's
     # matmul keeps f64 accuracy, same contract as ref.spmm_ell
     acc_dtype = jnp.promote_types(x.dtype, jnp.float32)
-    xg = jnp.take(x.T, chunk_cols, axis=1)             # [k_pad, T, C, W]
+    with jax.named_scope("spmv.gather"):
+        xg = jnp.take(x.T, chunk_cols, axis=1)         # [k_pad, T, C, W]
     parts = [_sell_segment(chunk_vals, xg, chunk_slice, *seg, kb=kb,
                            acc_dtype=acc_dtype, interpret=interpret)
              for seg in segments]

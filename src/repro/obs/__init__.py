@@ -17,20 +17,20 @@ Metrics (process-wide registry; one pane of glass over every cache and
 the serving counters)::
 
     obs.counter("plan_store.hits").inc()
-    obs.snapshot()   # {'counters': ..., 'gauges': ..., 'histograms': ...}
+    obs.snapshot()   # {'counters': ..., 'gauges': ...}
 """
 from .spans import (Span, TraceBuffer, enabled, install_sink,  # noqa: F401
-                    remove_sink, span, tracing)
-from .metrics import (REGISTRY, Counter, Gauge, Histogram,  # noqa: F401
-                      counter, gauge, histogram, reset, snapshot)
+                    record, remove_sink, span, timed, tracing)
+from .metrics import (REGISTRY, Counter, Gauge,  # noqa: F401
+                      counter, gauge, reset, snapshot)
 from .export import (to_chrome_trace, validate_chrome_trace,  # noqa: F401
                      write_chrome_trace, write_jsonl, write_trace)
 
 __all__ = [
-    "span", "tracing", "enabled", "install_sink", "remove_sink",
-    "Span", "TraceBuffer",
-    "counter", "gauge", "histogram", "snapshot", "reset",
-    "REGISTRY", "Counter", "Gauge", "Histogram",
+    "span", "timed", "record", "tracing", "enabled", "install_sink",
+    "remove_sink", "Span", "TraceBuffer",
+    "counter", "gauge", "snapshot", "reset",
+    "REGISTRY", "Counter", "Gauge",
     "to_chrome_trace", "write_chrome_trace", "write_jsonl",
     "write_trace", "validate_chrome_trace",
 ]

@@ -1,4 +1,4 @@
-"""Process-wide metrics registry: counters, gauges, histograms.
+"""Process-wide metrics registry: counters and gauges.
 
 One registry per process (module-level ``REGISTRY``); metrics are
 get-or-created by ``(name, labels)`` so every layer that counts the same
@@ -78,41 +78,11 @@ class Gauge:
         return self._v
 
 
-class Histogram:
-    """Streaming count/sum/min/max (enough for avg + extremes)."""
-
-    __slots__ = ("count", "sum", "min", "max", "_lock")
-
-    def __init__(self):
-        self.count = 0
-        self.sum = 0.0
-        self.min = None
-        self.max = None
-        self._lock = threading.Lock()
-
-    def observe(self, v):
-        v = float(v)
-        with self._lock:
-            self.count += 1
-            self.sum += v
-            if self.min is None or v < self.min:
-                self.min = v
-            if self.max is None or v > self.max:
-                self.max = v
-
-    def summary(self) -> dict:
-        with self._lock:
-            return {"count": self.count, "sum": self.sum,
-                    "min": self.min, "max": self.max,
-                    "avg": (self.sum / self.count) if self.count else None}
-
-
 class Registry:
     def __init__(self):
         self._lock = threading.Lock()
         self._counters: dict = {}
         self._gauges: dict = {}
-        self._histograms: dict = {}
 
     def _get(self, table: dict, cls, name: str, labels: dict):
         key = _key(name, labels)
@@ -128,9 +98,6 @@ class Registry:
     def gauge(self, name: str, **labels) -> Gauge:
         return self._get(self._gauges, Gauge, name, labels)
 
-    def histogram(self, name: str, **labels) -> Histogram:
-        return self._get(self._histograms, Histogram, name, labels)
-
     def total(self, name: str) -> float:
         """Sum of one counter name across all label sets."""
         return sum(c.value for (n, _), c in list(self._counters.items())
@@ -143,8 +110,6 @@ class Registry:
                          for k, c in sorted(self._counters.items())},
             "gauges": {_fmt(k): g.value
                        for k, g in sorted(self._gauges.items())},
-            "histograms": {_fmt(k): h.summary()
-                           for k, h in sorted(self._histograms.items())},
         }
 
     def reset(self) -> None:
@@ -152,7 +117,6 @@ class Registry:
         with self._lock:
             self._counters.clear()
             self._gauges.clear()
-            self._histograms.clear()
 
 
 REGISTRY = Registry()
@@ -164,10 +128,6 @@ def counter(name: str, **labels) -> Counter:
 
 def gauge(name: str, **labels) -> Gauge:
     return REGISTRY.gauge(name, **labels)
-
-
-def histogram(name: str, **labels) -> Histogram:
-    return REGISTRY.histogram(name, **labels)
 
 
 def snapshot() -> dict:

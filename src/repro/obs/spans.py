@@ -21,11 +21,23 @@ Timestamps are microseconds on the ``perf_counter_ns`` clock — an
 arbitrary but monotonic origin, which is all the Chrome-trace/Perfetto
 format needs. ``TraceBuffer.flush()`` returns events in a deterministic
 order (ts, tid, id) regardless of which thread emitted first.
+
+While a sink is installed, a span also opens a
+``jax.profiler.TraceAnnotation`` of its name (with the stat ``obs_span``,
+its id) when JAX is already imported, so a profiler trace shows the
+program's spans on the profiler's clock, beside the device's operations.
+
+``timed(name, **attrs)`` is a span that reads the clock whether or not a
+sink is installed: a caller that keeps its own duration (``Plan.reorder_ms``)
+reads ``.ms`` off it, so the record and the span time one interval.
+``record(name, t0_ns, t1_ns, **attrs)`` emits a span that has already
+ended, for durations reported after the fact (JAX's compile events).
 """
 from __future__ import annotations
 
 import itertools
 import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -56,10 +68,11 @@ _NULL = _NullSpan()
 
 
 class Span:
-    """A live span (tracing enabled). Use as a context manager."""
+    """A live span (tracing enabled, or `timed()`). Use as a context
+    manager."""
 
     __slots__ = ("name", "attrs", "id", "parent", "tid", "thread",
-                 "t0", "_open")
+                 "t0", "t1", "_open", "_ann")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
@@ -68,13 +81,19 @@ class Span:
         self.parent = None
         self.tid = 0
         self.thread = ""
-        self.t0 = 0
+        self.t0 = self.t1 = 0
         self._open = False
+        self._ann = None
 
     def set(self, **attrs):
         """Attach attributes after entry (e.g. a result computed inside)."""
         self.attrs.update(attrs)
         return self
+
+    @property
+    def ms(self) -> float:
+        """Milliseconds between entry and exit (0.0 before exit)."""
+        return (self.t1 - self.t0) / 1e6
 
     def __enter__(self):
         stack = getattr(_tls, "stack", None)
@@ -86,23 +105,45 @@ class Span:
         self.parent = stack[-1].id if stack else None
         stack.append(self)
         self._open = True
+        if _enabled:
+            self._ann = _annotation(self.name, self.id)
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, etype, evalue, tb):
-        t1 = time.perf_counter_ns()
+        t1 = self.t1 = time.perf_counter_ns()
         stack = getattr(_tls, "stack", None) or []
         # Force-close any descendants left open by a raise between their
         # __enter__ and __exit__ (they sit above us on the stack).
         while stack and stack[-1] is not self:
             dangling = stack.pop()
             dangling._open = False
+            dangling.t1 = t1
+            _close_annotation(dangling)
             _emit(dangling, t1, unclosed=True)
         if stack and stack[-1] is self:
             stack.pop()
         self._open = False
+        _close_annotation(self)
         _emit(self, t1, error=etype.__name__ if etype else None)
         return False
+
+
+def _annotation(name: str, span_id: int):
+    """An open profiler annotation of the span, where JAX is loaded (obs
+    never imports it)."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return None
+    ann = profiler.TraceAnnotation(name, obs_span=span_id)
+    ann.__enter__()
+    return ann
+
+
+def _close_annotation(span: Span) -> None:
+    if span._ann is not None:
+        span._ann.__exit__(None, None, None)
+        span._ann = None
 
 
 def _emit(span: Span, t1_ns: int, error=None, unclosed=False) -> None:
@@ -131,6 +172,26 @@ def span(name: str, **attrs):
     if not _enabled:
         return _NULL
     return Span(name, attrs)
+
+
+def timed(name: str, **attrs) -> Span:
+    """A span that reads the clock even with no sink installed; `.ms`
+    after exit. For callers that keep the duration themselves."""
+    return Span(name, attrs)
+
+
+def record(name: str, t0_ns: int, t1_ns: int, **attrs) -> None:
+    """Emit a span that ran from t0_ns to t1_ns (perf_counter_ns clock) on
+    this thread, under its innermost open span. Nothing while disabled."""
+    if not _enabled:
+        return
+    sp = Span(name, attrs)
+    t = threading.current_thread()
+    sp.tid, sp.thread = t.ident or 0, t.name
+    stack = getattr(_tls, "stack", None)
+    sp.parent = stack[-1].id if stack else None
+    sp.t0 = t0_ns
+    _emit(sp, t1_ns)
 
 
 def enabled() -> bool:
