@@ -90,7 +90,8 @@ class SpmvProblem:
       seed        — reordering seed (default 0)
       schemes     — scheme names plan(reorder="auto") should consider
                     (default: every registered scheme with auto_candidate)
-      block_shape — (bm, bn) / (C, W) for fixed block engines
+      block_shape — (bm, bn) / (C, W) for fixed block engines (default
+                    (8, 128); sell's (128, 8), a slice's rows on lanes)
       sell_sigma  — σ sort window for the fixed sell engine
       use_kernel  — "auto" | "pallas" | "interpret" | "ref"
       nnz_bucket  — CSR nnz padding bucket
@@ -142,6 +143,13 @@ def values_key(mat: CSRMatrix) -> str:
     return h.hexdigest()[:20]
 
 
+def _block_shape(hints: dict, engine: str) -> tuple:
+    """The hinted block shape, else the engine's own: one (8, 128) tile
+    for the block engines, and for sell (C, W) = (128, 8) chunks."""
+    return tuple(hints.get("block_shape",
+                           (128, 8) if engine == "sell" else (8, 128)))
+
+
 def plan_key(problem: SpmvProblem, reorder: str, engine: str,
              probe, seed: int, schemes=None, topology=None,
              partition: str = "auto", partitioners=None) -> str:
@@ -173,7 +181,7 @@ def plan_key(problem: SpmvProblem, reorder: str, engine: str,
     h.update(_mat_key(problem.mat).encode())
     h.update(f"{reorder}:{tuple(schemes or ())}:{seed}:{engine}:"
              f"{problem.dtype_name()}:"
-             f"{tuple(hints.get('block_shape', (8, 128)))}:"
+             f"{_block_shape(hints, engine)}:"
              f"{hints.get('sell_sigma')}:{int(hints.get('nnz_bucket', 0))}:"
              f"{probe}:{int(k)}".encode())
     if topo is not None:
@@ -769,7 +777,7 @@ def _plan_decide(problem: SpmvProblem, reorder: str = "auto",
     seed = int(hints.get("seed", 0))
     use_kernel = hints.get("use_kernel", "auto")
     nnz_bucket = int(hints.get("nnz_bucket", 0))
-    block_shape = tuple(hints.get("block_shape", (8, 128)))
+    block_shape = _block_shape(hints, engine)
     sell_sigma = hints.get("sell_sigma")
     k = max(int(problem.k), 1)
     topo = topology_mod.normalize(topology)

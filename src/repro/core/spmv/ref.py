@@ -95,14 +95,14 @@ def spmv_bell(blocks: jax.Array, block_cols: jax.Array, x2d: jax.Array) -> jax.A
 def spmv_sell(chunk_vals: jax.Array, chunk_cols: jax.Array,
               chunk_slice: jax.Array, x: jax.Array,
               num_slices: int) -> jax.Array:
-    """SELL-C-σ: chunk_vals/cols [T, C, W]; chunk_slice int32[T]
-    nondecreasing; x [n_pad, nv]. Returns y [S, C, nv] in slice order
-    (caller un-permutes via SellCS.inv_perm).
+    """SELL-C-σ, rows on lanes: chunk_vals/cols [T, W, C]; chunk_slice
+    int32[T] nondecreasing; x [n_pad, nv]. Returns y [S, C, nv] in slice
+    order (caller un-permutes via SellCS.inv_perm).
 
     Padding slots have val 0 (col 0), so they add exactly 0.
     """
-    gathered = gather(x, chunk_cols)                       # [T, C, W, nv]
-    partial = jnp.einsum("tcw,tcwv->tcv", chunk_vals, gathered,
+    gathered = gather(x, chunk_cols)                       # [T, W, C, nv]
+    partial = jnp.einsum("twc,twcv->tcv", chunk_vals, gathered,
                          preferred_element_type=jnp.float32,
                          precision=HIGHEST)
     y = jax.ops.segment_sum(partial, chunk_slice, num_segments=num_slices,

@@ -226,10 +226,11 @@ def cands_default(mat, feat):
 
 
 def cands_sell(mat, feat):
-    c = 8
-    w_fit = pick_chunk_width(mat)
+    # rows on lanes: a slice is one lane row (C = 128) and W a multiple of
+    # the f32 sublane count, so each chunk is whole (8, 128) tiles
+    c = 128
     out = []
-    for w in {w_fit, 128}:
+    for w in sorted({8, pick_chunk_width(mat)}):
         # σ = whole-matrix sort packs similar-degree rows best; the small
         # window keeps rows near their reordered position (cache locality)
         for sigma in (8 * c, max(int(feat["m"]), 1)):

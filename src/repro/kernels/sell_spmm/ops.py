@@ -1,7 +1,7 @@
 """Public wrapper for SELL-C-σ SpMM: dense RHS block in, dense block out.
 
 `sell_matmul(op, x)` is what `SellOperator.matmul` dispatches to for 2-D
-x — it handles k padding to the k-tile, the σ-sort un-permute, and the
+x — it handles k padding to the k-tile, the un-permute to rows, and the
 pallas / interpret / jnp-ref engine choice, mirroring
 kernels/sell_spmv/ops.py for the single-vector path. Both paths run the
 one SELL kernel (kernels/sell_spmv/kernel.py); here the k-tile is wider
@@ -40,7 +40,8 @@ def sell_matmul(op, x: jax.Array) -> jax.Array:
     """
     k = x.shape[1]
     with obs.span("kernel.spmm", engine="sell", k=int(k),
-                  use_kernel=op.use_kernel) as sp:
+                  use_kernel=op.use_kernel, chunks=op.num_chunks,
+                  slots=op.padded_nnz) as sp:
         if op.use_kernel in ("pallas", "interpret"):
             kb = pick_k_tile(k)
             sp.set(k_tile=int(kb))
@@ -52,7 +53,4 @@ def sell_matmul(op, x: jax.Array) -> jax.Array:
         else:
             y = sell_spmm_ref(op.chunk_vals, op.chunk_cols, op.chunk_slice,
                               x, op.num_slices)
-        # y is in slice order; inv_perm[r] = slice position of original
-        # row r
-        y = y.reshape(-1, y.shape[-1])[op.inv_perm]
-        return y[:, :k]
+        return op.rows(y)[:, :k]

@@ -1,8 +1,9 @@
 """Jit'd public wrapper for SELL-C-σ SpMV: host format in, vector out.
 
-Handles the σ-sort un-permute (inv_perm gather), the SMEM segments of
-the chunk grid and the pallas / interpret / jnp-ref engine choice,
-mirroring bell_spmv/ops.py.
+Handles the un-permute from slice order to rows (the inv_perm gather, 0
+for empty rows, plus the pieces of split rows), the SMEM segments of the
+chunk grid and the pallas / interpret / jnp-ref engine choice, mirroring
+bell_spmv/ops.py.
 """
 from __future__ import annotations
 
@@ -16,6 +17,11 @@ from ...core.sparse.sell import SellCS
 from ..segments import row_aligned_segments
 from .kernel import sell_spmm
 from .ref import sell_spmm_ref
+
+# The layout a stored operator holds: [T, W, C] chunks, a slice's rows on
+# lanes, rows stored as pieces. from_state refuses entries of another
+# layout (or none).
+LAYOUT = "rows_on_lanes"
 
 
 def _segments(chunk_slice, num_slices: int) -> tuple:
@@ -39,13 +45,16 @@ class SellOperator:
         self.chunk_cols = jnp.asarray(host.chunk_cols, dtype=jnp.int32)
         self.chunk_slice = jnp.asarray(host.chunk_slice, dtype=jnp.int32)
         self.inv_perm = jnp.asarray(host.inv_perm, dtype=jnp.int32)
+        self.split_pos = jnp.asarray(host.split_pos, dtype=jnp.int32)
+        self.split_row = jnp.asarray(host.split_row, dtype=jnp.int32)
         self.segments = _segments(host.chunk_slice, self.num_slices)
         self.use_kernel = resolve_use_kernel(use_kernel, "sell")
 
     def __call__(self, x: jax.Array) -> jax.Array:
         """x: [n] or [n, nv] -> y: [m] or [m, nv]."""
         with obs.span("kernel.spmv", engine="sell",
-                      use_kernel=self.use_kernel):
+                      use_kernel=self.use_kernel, chunks=self.num_chunks,
+                      slots=self.padded_nnz):
             squeeze = x.ndim == 1
             if squeeze:
                 x = x[:, None]
@@ -58,10 +67,16 @@ class SellOperator:
             else:
                 y = sell_spmm_ref(self.chunk_vals, self.chunk_cols,
                                   self.chunk_slice, x, self.num_slices)
-            # y is in slice order; inv_perm[r] = slice position of
-            # original row r
-            y = y.reshape(-1, nv)[self.inv_perm]
+            y = self.rows(y)
             return y[:, 0] if squeeze else y
+
+    def rows(self, y: jax.Array) -> jax.Array:
+        """y[S, C, k] in slice order -> [m, k] by row: each row's first
+        piece (0 for an empty row, whose position is past the end), plus
+        the further pieces of split rows."""
+        y = y.reshape(-1, y.shape[-1])
+        out = y.at[self.inv_perm].get(mode="fill", fill_value=0)
+        return out.at[self.split_row].add(y[self.split_pos])
 
     def matmul(self, x: jax.Array) -> jax.Array:
         """x: [n, k] -> y: [m, k] via the k-tiled SpMM kernel
@@ -77,15 +92,22 @@ class SellOperator:
     def state(self):
         meta = {"shape": list(self.shape), "c": self.c, "sigma": self.sigma,
                 "w": self.w, "num_slices": self.num_slices,
-                "use_kernel": self.use_kernel}
+                "use_kernel": self.use_kernel, "layout": LAYOUT}
         arrays = {"chunk_vals": np.asarray(self.chunk_vals),
                   "chunk_cols": np.asarray(self.chunk_cols),
                   "chunk_slice": np.asarray(self.chunk_slice),
-                  "inv_perm": np.asarray(self.inv_perm)}
+                  "inv_perm": np.asarray(self.inv_perm),
+                  "split_pos": np.asarray(self.split_pos),
+                  "split_row": np.asarray(self.split_row)}
         return meta, arrays
 
     @classmethod
     def from_state(cls, meta, arrays, dtype=jnp.float32):
+        if meta.get("layout") != LAYOUT:
+            # an entry written before rows on lanes holds its chunks
+            # transposed: the stores read the refusal as a miss and rebuild
+            raise ValueError(f"stored SELL layout {meta.get('layout')!r}, "
+                             f"this code reads {LAYOUT!r}")
         op = object.__new__(cls)
         op.shape = tuple(meta["shape"])
         op.c, op.sigma, op.w = meta["c"], meta["sigma"], meta["w"]
@@ -95,8 +117,15 @@ class SellOperator:
         op.chunk_cols = jnp.asarray(arrays["chunk_cols"])
         op.chunk_slice = jnp.asarray(arrays["chunk_slice"])
         op.inv_perm = jnp.asarray(arrays["inv_perm"])
+        op.split_pos = jnp.asarray(arrays["split_pos"])
+        op.split_row = jnp.asarray(arrays["split_row"])
         op.segments = _segments(arrays["chunk_slice"], op.num_slices)
         return op
+
+    @property
+    def num_chunks(self) -> int:
+        """Chunks, which is the kernel's grid steps per k-tile."""
+        return int(self.chunk_vals.shape[0])
 
     @property
     def padded_nnz(self) -> int:

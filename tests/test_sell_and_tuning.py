@@ -18,7 +18,7 @@ from repro.core.reorder import api as reorder_api
 from repro.core.sparse.csr import CSRMatrix
 from repro.core.sparse.sell import (pick_chunk_width, sell_padded_nnz,
                                     sell_to_dense, to_sell)
-from repro.core.spmv.ops import DeviceELL, build_operator
+from repro.core.spmv.ops import DeviceELL, build_operator, make_engine
 from repro.matrices import generators as G
 
 ENGINES = ["csr", "ell", "sell", "bell", "bcsr"]
@@ -67,23 +67,114 @@ def test_property_sell_matches_oracle_on_skew(m, seed):
         assert np.abs(got - want).max() / scale < 1e-5, (c, sigma, w)
 
 
+def _ragged_matrix(m: int = 1100, seed: int = 8) -> CSRMatrix:
+    """A power-law matrix with m not a multiple of 128, every 7th row
+    empty, and one row of min(1050, m - 10) nonzeros: over many chunks,
+    and cut into pieces at (C, W) = (128, 8)."""
+    a = G.power_law(m, alpha=1.9, seed=seed).to_dense()
+    a[::7] = 0.0
+    rng = np.random.default_rng(seed)
+    long = min(1050, m - 10)
+    a[m // 2, rng.choice(m, long, replace=False)] = rng.standard_normal(long)
+    return CSRMatrix.from_dense(a)
+
+
 def test_sell_roundtrip_and_perm():
-    mat = G.power_law(200, alpha=1.9, seed=7)
+    mat = _ragged_matrix(300, seed=7)
     s = to_sell(mat, c=8, sigma=64, w=16)
+    # rows on lanes: a chunk is [W, C]
+    assert s.chunk_vals.shape == s.chunk_cols.shape == (s.num_chunks, 16, 8)
     assert np.allclose(sell_to_dense(s), mat.to_dense())
-    # row_perm restricted to real rows is a permutation of [0, m)
+    # row_perm restricted to real rows holds each row once per piece of at
+    # most C·W elements, and an empty row not at all
+    counts = mat.row_nnz()
+    assert counts.max() > 8 * 16 and (counts == 0).any()
     real = s.row_perm[s.row_perm < mat.m]
-    assert np.array_equal(np.sort(real), np.arange(mat.m))
+    assert np.array_equal(np.bincount(real, minlength=mat.m),
+                          -(-counts // (8 * 16)))
     assert s.padded_nnz == sell_padded_nnz(mat, 8, 64, 16)
 
 
-def test_sell_interpret_kernel_matches_ref():
-    mat = G.power_law(128, alpha=1.9, seed=8)
-    x = np.random.default_rng(8).standard_normal(mat.n)
-    ops = [build_operator(mat, "sell", block_shape=(8, 16), use_kernel=uk)
-           for uk in ("ref", "interpret")]
-    outs = [np.asarray(op(jnp.asarray(x, jnp.float32))) for op in ops]
-    assert np.allclose(outs[0], outs[1], atol=1e-5 * (np.abs(outs[0]).max() + 1))
+@pytest.mark.parametrize("use_kernel", ["ref", "interpret"])
+@pytest.mark.parametrize("k", [1, 8])
+@pytest.mark.parametrize("c,w,sigma", [(128, 8, 1), (128, 8, 1024),
+                                       (128, 8, "m"), (128, 32, "m"),
+                                       (8, 16, 64)])
+def test_sell_interpret_kernel_matches_ref(c, w, sigma, k, use_kernel):
+    """Rows on lanes against the float64 oracle, on the kernel (interpret)
+    and on the jnp reference: empty rows, m off the slice height, a row
+    over many chunks, σ = 1, 8·C and m, SpMV (k = 1) and SpMM (k = 8)."""
+    mat = _ragged_matrix()
+    assert mat.m % 128 and (mat.row_nnz() == 0).any()
+    sigma = mat.m if sigma == "m" else sigma
+    op = make_engine(mat, "sell", block_shape=(c, w), sell_sigma=sigma,
+                     use_kernel=use_kernel)
+    assert op.chunk_vals.shape[1:] == (w, c)
+    assert mat.row_nnz().max() > 4 * w        # the long row spans chunks
+    # it is stored as pieces where it is longer than C·W
+    assert (op.split_row.shape[0] > 0) == (mat.row_nnz().max() > c * w)
+    rng = np.random.default_rng(k)
+    if k == 1:
+        x = rng.standard_normal(mat.n)
+        want = mat.spmv(x)
+        got = np.asarray(op(jnp.asarray(x, jnp.float32)))
+    else:
+        x = rng.standard_normal((mat.n, k))
+        want = mat.to_dense() @ x
+        got = np.asarray(op.matmul(jnp.asarray(x, jnp.float32)))
+    assert got.shape == want.shape
+    assert np.abs(got - want).max() / (np.abs(want).max() + 1e-9) < 1e-5
+
+
+def test_plan_store_rebuilds_an_old_layout_sell_entry(tmp_path, monkeypatch):
+    """A SELL entry written with [T, C, W] chunks (no layout in its meta)
+    reads as a miss: the operator is rebuilt, right, and stored anew."""
+    import json
+
+    from repro.api import Plan, SpmvProblem, plan
+
+    store = tmp_path / "plans"
+    monkeypatch.setenv("REPRO_PLAN_CACHE", str(store))
+    mat = _ragged_matrix(300)
+    pl = plan(SpmvProblem(mat, hints={"use_kernel": "ref"}),
+              reorder="baseline", engine="sell")
+    pl.build()
+    jpath, zpath = store / f"{pl.key}.json", store / f"{pl.key}.npz"
+    rec = json.loads(jpath.read_text())
+    assert rec["op"]["meta"].pop("layout") == "rows_on_lanes"
+    jpath.write_text(json.dumps(rec))
+    z = dict(np.load(zpath))
+    for name in ("op__chunk_vals", "op__chunk_cols"):
+        z[name] = z[name].transpose(0, 2, 1)
+    del z["op__split_pos"], z["op__split_row"]
+    np.savez(zpath, **z)
+
+    old = Plan.load(pl.key, mat=mat)
+    assert old is not None and old.cache_hit
+    op = old.build()
+    assert not op.build_info["cache_hit"]
+    x = np.random.default_rng(3).standard_normal(mat.n)
+    got = np.asarray(op(jnp.asarray(x, jnp.float32)))
+    want = mat.spmv(x)
+    assert np.abs(got - want).max() / (np.abs(want).max() + 1e-9) < 1e-5
+    assert Plan.load(pl.key, mat=mat).build().build_info["cache_hit"]
+
+
+def test_sell_spans_count_chunks_and_slots():
+    from repro import obs
+
+    mat = _ragged_matrix(300)
+    op = make_engine(mat, "sell", block_shape=(128, 8), sell_sigma=mat.m,
+                     use_kernel="interpret")
+    with obs.tracing() as buf:
+        op(jnp.ones(mat.n, jnp.float32))
+        op.matmul(jnp.ones((mat.n, 8), jnp.float32))
+    got = {ev["name"]: ev["args"] for ev in buf.flush()
+           if ev["name"] in ("kernel.spmv", "kernel.spmm")}
+    want = {"chunks": op.chunk_vals.shape[0],
+            "slots": int(np.prod(op.chunk_vals.shape))}
+    for name in ("kernel.spmv", "kernel.spmm"):
+        assert {a: got[name][a] for a in want} == want, name
 
 
 def test_sell_beats_ell_padding_2x_on_power_law():

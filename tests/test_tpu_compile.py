@@ -9,7 +9,10 @@ nothing runs, no chip is needed.
 Shapes are those of the HPCG-style operator `stencil_3d(104)` (1,124,864
 rows, 7,809,152 nnz) under `rcm`, as the planner builds it with the
 default (8, 128) blocks: Block-ELL 140,608 block rows x K = 7, BCSR
-592,192 blocks, SELL-C-σ 140,608 chunks. At these sizes the unsegmented
+592,192 blocks. SELL-C-σ is compiled at the shape of the Graph500 scale-21
+PageRank operator, its (C, W) = (128, 8) chunks laid rows on lanes: 85,072
+chunks over 2,097,152 columns (at W = 32, the same slots in a quarter of
+the chunks). At these sizes the unsegmented
 Block-ELL and BCSR kernels were refused ("Ran out of memory in memory
 space smem"); each pallas_call now covers one SMEM segment, so the
 program holds several.
@@ -37,7 +40,8 @@ NBR = M // 8                  # block rows at bm = 8
 NCB = -(-M // 128)            # column blocks at bn = 128
 BELL_K = 7                    # max blocks per block row under rcm
 BCSR_T = 592_192              # nonempty (8, 128) blocks under rcm
-SELL_T = NBR                  # one chunk per slice (row nnz <= 7 <= W)
+SELL_SLOTS = 85_072 * 8 * 128  # [W, C] chunks of Graph500 scale 21 at W = 8
+SELL_N = 2 ** 21              # its columns
 ELL_K = 7                     # max row nnz
 
 
@@ -90,16 +94,37 @@ def test_bcsr_compiles_for_v5e(sds, k):
     assert _compiled_kernels(lowered) >= len(segs)
 
 
-@pytest.mark.parametrize("w", [8, 128])
+def _sell_chunk_shapes() -> set:
+    """(W, C) of the tuner's SELL candidates on rows of 27 nonzeros."""
+    from repro.core.sparse.csr import CSRMatrix
+    from repro.core.spmv.tune import cands_sell, matrix_features
+
+    m, deg = 1024, 27
+    rng = np.random.default_rng(0)
+    cols = np.concatenate([rng.choice(m, deg, replace=False)
+                           for _ in range(m)])
+    mat = CSRMatrix.from_coo(np.repeat(np.arange(m), deg), cols,
+                             np.ones(m * deg, np.float32), (m, m))
+    return {(cd["block_shape"][1], cd["block_shape"][0])
+            for cd in cands_sell(mat, matrix_features(mat))}
+
+
+@pytest.mark.parametrize("w", [8, 32])
 @pytest.mark.parametrize("k", [1, 8])
 def test_sell_compiles_for_v5e(sds, w, k):
-    segs = row_aligned_segments(np.arange(SELL_T + 1), n_arrays=1)
-    lowered = sell_spmm.lower(sds((SELL_T, 8, w), jnp.float32),
-                              sds((SELL_T, 8, w), jnp.int32),
-                              sds((SELL_T,), jnp.int32),
-                              sds((M, k), jnp.float32),
-                              num_slices=SELL_T, kb=k, segments=segs)
-    assert _compiled_kernels(lowered) >= len(segs)
+    chunk = (w, 128)
+    assert chunk in _sell_chunk_shapes()
+    # a chunk is a whole number of f32 (8, 128) tiles: no HBM tile padding
+    assert chunk[0] % 8 == 0 and chunk[1] % 128 == 0
+    t = SELL_SLOTS // (w * 128)
+    segs = row_aligned_segments(np.arange(t + 1), n_arrays=1)
+    assert len(segs) == 1
+    lowered = sell_spmm.lower(sds((t,) + chunk, jnp.float32),
+                              sds((t,) + chunk, jnp.int32),
+                              sds((t,), jnp.int32),
+                              sds((SELL_N, k), jnp.float32),
+                              num_slices=t, kb=k, segments=segs)
+    assert _compiled_kernels(lowered) == 1
 
 
 @pytest.mark.parametrize("k", [1, 8])
