@@ -7,7 +7,10 @@ found by the name that `BENCHMARK.json` gives:
     configs/<config>.json     the configuration's sizes; `generator` names
                               the module under generators/ that builds it
     traffic/<traffic>.json    the traffic mix: row order, plan request,
-                              the loop under loops/ and its parameters
+                              the loop under loops/ and its parameters;
+                              `k` (default 1), the width of the block the
+                              loop hands to the operator, which the plan
+                              is priced for (`SpmvProblem.k`)
     cells/<workload>.json     the limits of the cell's correctness checks
     metrics/<metric>.py       a per-layer metric's reader: read(reading)
                               returns a number, or None where it finds
@@ -118,13 +121,24 @@ class CompileClock:
 @dataclasses.dataclass
 class Reading:
     """What the per-layer readers read: set-up spans and compile time,
-    and the traced window reduced (trace_reduce.reduce, or None)."""
+    the traced window reduced (trace_reduce.reduce, or None), and the
+    window's device seconds under each program scope segment
+    (trace_scopes.reduce's `scopes`, or None where the trace has no window
+    or the XPlane proto is missing)."""
     spans: list
     compile_setup_s: float
     units: int
     least_bytes_per_unit: int
     peaks: dict | None
     trace: dict | None
+    scopes: dict | None = None
+
+    def scope_per_unit_s(self, scope: str):
+        """Device seconds under `scope` per unit of the window; None where
+        the trace has no scopes or no op carries `scope`."""
+        if self.scopes is None or scope not in self.scopes:
+            return None
+        return self.scopes[scope] / self.units
 
     def roofline_pct(self):
         """Least bytes of the window's work at peak bandwidth, over the
@@ -159,7 +173,8 @@ def setup(bench: Bench, cell: Cell, seed: int, dtype=None):
         mat = matrices.load(matrices.cache_path(bench.root, cell.config_name,
                                                 tr["order"]))
     with jax.profiler.TraceAnnotation("bench.setup.plan"):
-        pl = plan(SpmvProblem(mat, dtype=dtype), reorder=tr["reorder"],
+        pl = plan(SpmvProblem(mat, k=int(tr.get("k", 1)), dtype=dtype),
+                  reorder=tr["reorder"],
                   engine=tr["engine"], probe=False, cache=False)
     with jax.profiler.TraceAnnotation("bench.setup.build"):
         op = pl.build(cache=False)
@@ -211,7 +226,7 @@ def run_cell(bench: Bench, cell: Cell, seed: int, seconds: float,
 
     from repro import obs
 
-    from bench import peaks, trace_reduce
+    from bench import peaks, trace_reduce, trace_scopes
 
     devices = jax.devices()[:cell.chips]
     clock = CompileClock()
@@ -227,13 +242,16 @@ def run_cell(bench: Bench, cell: Cell, seed: int, seconds: float,
 
     trace_dir = os.path.join(bench.root, ".cache", "bench", "trace")
     if trace:
+        # the program's spans on through the window: they annotate the trace
         shutil.rmtree(trace_dir, ignore_errors=True)
         jax.profiler.start_trace(trace_dir)
-    try:
-        units, elapsed = window(loop, seconds)
-    finally:
-        if trace:
+        try:
+            with obs.tracing():
+                units, elapsed = window(loop, seconds)
+        finally:
             jax.profiler.stop_trace()
+    else:
+        units, elapsed = window(loop, seconds)
     in_window = (clock.count - compile_setup[1], clock.secs - compile_setup[0])
     fallback = obs.REGISTRY.total("kernel.ref_fallback")
     log(f"[window] {units} units in {elapsed:.6f} s; compiled "
@@ -245,9 +263,10 @@ def run_cell(bench: Bench, cell: Cell, seed: int, seconds: float,
     if devices[0].platform == "tpu" and fallback:
         raise RuntimeError("a Pallas engine ran its jnp reference on a TPU")
 
-    reduced = None
+    reduced = scoped = None
     if trace:
         reduced = trace_reduce.reduce(trace_reduce.load(trace_dir))
+        scoped = trace_scopes.reduce(trace_scopes.load(trace_dir))
         shutil.rmtree(trace_dir, ignore_errors=True)
         if reduced is not None:
             device["busy_s"] = reduced["busy_s"]
@@ -273,7 +292,8 @@ def run_cell(bench: Bench, cell: Cell, seed: int, seconds: float,
         reading = Reading(spans=spans.flush(),
                           compile_setup_s=compile_setup[0], units=units,
                           least_bytes_per_unit=least, peaks=kind_peaks,
-                          trace=reduced)
+                          trace=reduced,
+                          scopes=None if scoped is None else scoped["scopes"])
         for m in cell.per_layer:
             value = bench.reader(m["name"]).read(reading)
             if value is not None:

@@ -13,7 +13,13 @@ from __future__ import annotations
 
 def spmv(nnz: int, m: int, n: int, itemsize: int = 4) -> int:
     """y = A x: the values once, x once (n), y once (m)."""
-    return itemsize * (nnz + n + m)
+    return spmm(nnz, m, n, 1, itemsize)
+
+
+def spmm(nnz: int, m: int, n: int, k: int, itemsize: int = 4) -> int:
+    """Y = A X over k columns: the values once, and each of the k columns
+    of X (n) and of Y (m) once. `spmv` at k = 1."""
+    return itemsize * (nnz + k * (n + m))
 
 
 def cg_iteration(nnz: int, n: int, itemsize: int = 4) -> int:

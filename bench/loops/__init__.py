@@ -13,6 +13,12 @@ the traffic's parameters, the run's seed and the compute dtype:
 
 A unit is the work one end-to-end sample stands for: a CG set, a PageRank
 iteration.
+
+A traffic file may state `k`, the width of the block its loop hands to
+`op.matmul` (default 1); the harness plans the operator for that width
+(`SpmvProblem(mat, k=k)`, a plan labelled `@k<k>`), so a loop over
+k-column blocks runs an operator the tuner priced for them, and counts
+its least bytes with `least_bytes.spmm` over the same k.
 """
 from __future__ import annotations
 

@@ -21,8 +21,12 @@ TINY_TRAFFIC = {
     "cg8-shuffled": {"loop": "cg", "order": "shuffle-1", "reorder": "auto",
                      "engine": "auto", "max_iter": 8, "check_units": 2,
                      "metric": "cg_solve_s"},
+    "cg8-natural": {"loop": "cg", "order": "natural", "reorder": "auto",
+                    "engine": "auto", "max_iter": 8, "check_units": 2,
+                    "metric": "cg_solve_s"},
 }
 CG_CELL = "hpcg27-6.cg8-shuffled"
+CG_NATURAL_CELL = "hpcg27-6.cg8-natural"
 PR_CELL = "g500-s8.pagerank"
 
 
@@ -33,8 +37,8 @@ def write_json(path: str, obj) -> None:
 
 
 def tiny_root(tmp: str) -> str:
-    """A checkout under `tmp` whose BENCHMARK.json names the two tiny
-    cells; the metric readers, the PageRank traffic and the cells' limits
+    """A checkout under `tmp` whose BENCHMARK.json names the tiny cells,
+    one for each real cell; the metric readers, the PageRank traffic and the cells' limits
     are the real files."""
     root = os.path.join(tmp, "checkout")
     shutil.copytree(os.path.join(BENCH, "metrics"),
@@ -49,6 +53,7 @@ def tiny_root(tmp: str) -> str:
     with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
         real = json.load(f)
     rename = {"hpcg27-104.cg50-shuffled": CG_CELL,
+              "hpcg27-104.cg50-natural": CG_NATURAL_CELL,
               "g500-s21.pagerank": PR_CELL}
     os.makedirs(os.path.join(root, "bench", "cells"))
     for real_cell, cell in rename.items():
@@ -61,6 +66,8 @@ def tiny_root(tmp: str) -> str:
     manifest["workloads"] = [
         {"name": CG_CELL, "config": "hpcg27-6", "traffic": "cg8-shuffled",
          "chips": 1, "why": "tiny"},
+        {"name": CG_NATURAL_CELL, "config": "hpcg27-6",
+         "traffic": "cg8-natural", "chips": 1, "why": "tiny"},
         {"name": PR_CELL, "config": "g500-s8", "traffic": "pagerank",
          "chips": 1, "why": "tiny"}]
     for m in manifest["end_to_end"] + manifest["per_layer"]:

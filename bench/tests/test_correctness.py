@@ -13,7 +13,7 @@ from repro.core.spmv import plan as plan_mod
 from bench import harness
 from bench.tests import fixtures
 
-CELLS = [fixtures.CG_CELL, fixtures.PR_CELL]
+CELLS = [fixtures.CG_CELL, fixtures.CG_NATURAL_CELL, fixtures.PR_CELL]
 REAL = plan_mod.Operator.__call__
 
 

@@ -24,8 +24,30 @@ def test_cell_files_found_by_name(root):
     assert cell.traffic == fixtures.TINY_TRAFFIC["cg8-shuffled"]
     assert set(cell.limits) == {"x_err"}
     assert [m["name"] for m in cell.end_to_end] == ["setup_s", "cg_solve_s"]
-    assert [m["name"] for m in cell.per_layer] == [
-        "plan_s", "compile_s", "cg.roofline", "device.idle.cg"]
+    names = [m["name"] for m in cell.per_layer]
+    assert names == [
+        m["name"] for m in harness.Bench(root).manifest["per_layer"]
+        if fixtures.CG_CELL in m.get("workloads", [fixtures.CG_CELL])]
+    assert {"plan_s", "compile_s", "cg.roofline", "device.idle.cg",
+            "cg.spmv_s", "cg.permute_s", "reorder_s", "tune_s",
+            "lower_s"} <= set(names)
+
+
+@pytest.mark.parametrize("k", [None, 8])
+def test_traffic_k_reaches_the_plan(tmp_path, k):
+    """A traffic's `k` is the width the plan is priced for; a traffic
+    without one plans at k = 1, under the label it has always had."""
+    root = fixtures.tiny_root(str(tmp_path))
+    bench = harness.Bench(root)
+    cell = bench.cell(fixtures.CG_CELL)
+    if k is not None:
+        cell.traffic["k"] = k
+    _, op, _ = harness.setup(bench, cell, seed=3)
+    label = op.plan.label()
+    if k is None:
+        assert op.plan.k == 1 and "@k" not in label
+    else:
+        assert op.plan.k == k and label.endswith(f"@k{k}")
 
 
 def test_unknown_workload_names_the_known_ones(root):

@@ -24,9 +24,21 @@ def test_hpcg27_104_per_iteration():
         1.7846e-4, rel=1e-3)
 
 
+@pytest.mark.parametrize("nnz,m,n", [(1000, 64, 64), (29_791_000,
+                                     1_124_864, 1_124_864), (10, 3, 4)])
+def test_spmm_is_spmv_at_k1_and_linear_in_k(nnz, m, n):
+    assert least_bytes.spmm(nnz, m, n, 1) == least_bytes.spmv(nnz, m, n)
+    step = least_bytes.spmm(nnz, m, n, 2) - least_bytes.spmm(nnz, m, n, 1)
+    assert step == 4 * (n + m)
+    for k in (3, 8, 100):
+        assert least_bytes.spmm(nnz, m, n, k) == \
+            least_bytes.spmm(nnz, m, n, 1) + (k - 1) * step
+
+
 def test_itemsize_scales_every_term():
     assert least_bytes.spmv(10, 3, 4, itemsize=8) == 8 * 17
     assert least_bytes.cg_iteration(10, 3, itemsize=2) == 2 * 28
+    assert least_bytes.spmm(10, 3, 4, 100, itemsize=2) == 2 * 710
 
 
 @pytest.mark.parametrize("reorder,engine", [
