@@ -1,6 +1,22 @@
-"""A benchmark checkout in miniature, for the tests: the real manifest's
-shape and files, with tiny configurations and short CG sets, so that a
-whole run fits a test on the CPU."""
+"""A benchmark checkout in miniature, for the tests: the real manifest with
+its real cell names and files, each configuration replaced by a tiny one
+and each traffic mix shortened where it needs it, so that a whole run
+fits a test on the CPU.
+
+The tiny counterparts are files found by the real names:
+
+    tests/tiny/configs/<config>.json    the whole tiny configuration, one
+                                        for each configuration of the
+                                        manifest
+    tests/tiny/traffic/<traffic>.json   keys merged onto the real traffic
+                                        file, only where it needs them
+
+So adding a configuration means adding its tiny file under
+`tests/tiny/configs/`, and nothing else in the tests; adding a cell over
+a configuration that has one, or a traffic mix that runs at a tiny size
+as it is, needs no file here at all. Every cell of the manifest gets the
+correctness tests of `test_correctness.py`.
+"""
 from __future__ import annotations
 
 import json
@@ -9,25 +25,15 @@ import shutil
 import time
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+REPO = os.path.dirname(BENCH)
+CG_CELL = "hpcg27-104.cg50-shuffled"
+CG_NATURAL_CELL = "hpcg27-104.cg50-natural"
+PR_CELL = "g500-s21.pagerank"
 
-TINY_CONFIGS = {
-    "hpcg27-6": {"generator": "hpcg27", "nx": 6, "ny": 6, "nz": 5,
-                 "diagonal": 26.0, "off_diagonal": -1.0, "dtype": "float32"},
-    "g500-s8": {"generator": "graph500", "scale": 8, "edgefactor": 16,
-                "A": 0.57, "B": 0.19, "C": 0.19, "edge_seed": 20,
-                "label_seed": 21, "dtype": "float32"},
-}
-TINY_TRAFFIC = {
-    "cg8-shuffled": {"loop": "cg", "order": "shuffle-1", "reorder": "auto",
-                     "engine": "auto", "max_iter": 8, "check_units": 2,
-                     "metric": "cg_solve_s"},
-    "cg8-natural": {"loop": "cg", "order": "natural", "reorder": "auto",
-                    "engine": "auto", "max_iter": 8, "check_units": 2,
-                    "metric": "cg_solve_s"},
-}
-CG_CELL = "hpcg27-6.cg8-shuffled"
-CG_NATURAL_CELL = "hpcg27-6.cg8-natural"
-PR_CELL = "g500-s8.pagerank"
+
+def read_json(path: str):
+    with open(path) as f:
+        return json.load(f)
 
 
 def write_json(path: str, obj) -> None:
@@ -36,43 +42,51 @@ def write_json(path: str, obj) -> None:
         json.dump(obj, f, indent=1)
 
 
-def tiny_root(tmp: str) -> str:
-    """A checkout under `tmp` whose BENCHMARK.json names the tiny cells,
-    one for each real cell; the metric readers, the PageRank traffic and the cells' limits
-    are the real files."""
+def tiny_config(name: str, bench: str = BENCH) -> dict:
+    """The tiny counterpart of configuration `name`; a configuration
+    without one is an error that names the file to add."""
+    path = os.path.join(bench, "tests", "tiny", "configs", name + ".json")
+    if not os.path.exists(path):
+        raise FileNotFoundError(
+            f"configuration {name!r} has no tiny counterpart for the tests: "
+            f"add {path}")
+    return read_json(path)
+
+
+def tiny_traffic(name: str, bench: str = BENCH) -> dict:
+    """The real traffic file `name` with its tiny overrides, if any."""
+    tr = read_json(os.path.join(bench, "traffic", name + ".json"))
+    path = os.path.join(bench, "tests", "tiny", "traffic", name + ".json")
+    if os.path.exists(path):
+        tr.update(read_json(path))
+    return tr
+
+
+def manifest_workloads(repo: str = REPO) -> list:
+    """The names of the cells in `repo`'s BENCHMARK.json."""
+    return [w["name"]
+            for w in read_json(os.path.join(repo, "BENCHMARK.json"))
+            ["workloads"]]
+
+
+def tiny_root(tmp: str, repo: str = REPO) -> str:
+    """A checkout under `tmp` with `repo`'s manifest, cell names, metric
+    readers and limits, its configurations replaced by their tiny files and
+    its traffic by the real files with their tiny overrides."""
+    bench = os.path.join(repo, "bench")
     root = os.path.join(tmp, "checkout")
-    shutil.copytree(os.path.join(BENCH, "metrics"),
-                    os.path.join(root, "bench", "metrics"))
-    shutil.copytree(os.path.join(BENCH, "traffic"),
-                    os.path.join(root, "bench", "traffic"))
-    for name, conf in TINY_CONFIGS.items():
-        write_json(os.path.join(root, "bench", "configs", name + ".json"),
-                   conf)
-    for name, tr in TINY_TRAFFIC.items():
-        write_json(os.path.join(root, "bench", "traffic", name + ".json"), tr)
-    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
-        real = json.load(f)
-    rename = {"hpcg27-104.cg50-shuffled": CG_CELL,
-              "hpcg27-104.cg50-natural": CG_NATURAL_CELL,
-              "g500-s21.pagerank": PR_CELL}
-    os.makedirs(os.path.join(root, "bench", "cells"))
-    for real_cell, cell in rename.items():
-        shutil.copy(os.path.join(BENCH, "cells", real_cell + ".json"),
-                    os.path.join(root, "bench", "cells", cell + ".json"))
-    manifest = dict(real)
-    manifest["configs"] = [
-        {"name": n, "source": "test", "file": f"bench/configs/{n}.json",
-         "reduced": [], "why": "tiny"} for n in TINY_CONFIGS]
-    manifest["workloads"] = [
-        {"name": CG_CELL, "config": "hpcg27-6", "traffic": "cg8-shuffled",
-         "chips": 1, "why": "tiny"},
-        {"name": CG_NATURAL_CELL, "config": "hpcg27-6",
-         "traffic": "cg8-natural", "chips": 1, "why": "tiny"},
-        {"name": PR_CELL, "config": "g500-s8", "traffic": "pagerank",
-         "chips": 1, "why": "tiny"}]
-    for m in manifest["end_to_end"] + manifest["per_layer"]:
-        if "workloads" in m:
-            m["workloads"] = [rename[w] for w in m["workloads"]]
+    out = os.path.join(root, "bench")
+    manifest = read_json(os.path.join(repo, "BENCHMARK.json"))
+    shutil.copytree(os.path.join(bench, "metrics"),
+                    os.path.join(out, "metrics"))
+    shutil.copytree(os.path.join(bench, "cells"), os.path.join(out, "cells"))
+    for conf in manifest["configs"]:
+        name = conf["name"]
+        conf["file"] = f"bench/configs/{name}.json"
+        write_json(os.path.join(root, conf["file"]), tiny_config(name, bench))
+    for name in {w["traffic"] for w in manifest["workloads"]}:
+        write_json(os.path.join(out, "traffic", name + ".json"),
+                   tiny_traffic(name, bench))
     write_json(os.path.join(root, "BENCHMARK.json"), manifest)
     return root
 

@@ -3,8 +3,11 @@ program's own bfloat16 path, one precision below the configuration's
 float32) fails, and so does a run whose timed path is broken underneath:
 a step that returns its state unchanged, half of the answer left out, an
 answer altered where it is produced, one PageRank step wrong in a long
-chain. The cells run on one chip, so there is no exchange between chips
-to leave out."""
+chain. Every cell of the real manifest gets these tests. The faults are
+planted in `Operator._apply`, which the operator's call and its `matmul`
+share, so they reach a loop over either, and act alike on an answer of
+shape [m] and one of shape [m, k]. The cells run on one chip, so there is
+no exchange between chips to leave out."""
 import jax.numpy as jnp
 import pytest
 
@@ -13,8 +16,8 @@ from repro.core.spmv import plan as plan_mod
 from bench import harness
 from bench.tests import fixtures
 
-CELLS = [fixtures.CG_CELL, fixtures.CG_NATURAL_CELL, fixtures.PR_CELL]
-REAL = plan_mod.Operator.__call__
+CELLS = fixtures.manifest_workloads()
+REAL = plan_mod.Operator._apply
 
 
 @pytest.fixture(scope="module")
@@ -38,25 +41,26 @@ def test_control_fails(root, cell):
     assert line["correct"] is False and line["failed"] > 0
 
 
-def unchanged(self, x, permuted=False):
+def unchanged(self, engine, x, permuted):
     return x
 
 
-def half_dropped(self, x, permuted=False):
-    y = REAL(self, x, permuted)
+def half_dropped(self, engine, x, permuted):
+    y = REAL(self, engine, x, permuted)
     return y.at[y.shape[0] // 2:].set(0)
 
 
-def one_altered(self, x, permuted=False):
-    y = REAL(self, x, permuted)
-    return y.at[jnp.argmax(jnp.abs(y))].multiply(1.01)
+def one_altered(self, engine, x, permuted):
+    y = REAL(self, engine, x, permuted)
+    return y.at[jnp.unravel_index(jnp.argmax(jnp.abs(y)), y.shape)].multiply(
+        1.01)
 
 
 @pytest.mark.parametrize("cell", CELLS)
 @pytest.mark.parametrize("fault", [unchanged, half_dropped, one_altered],
                          ids=lambda f: f.__name__)
 def test_broken_timed_path_is_not_correct(root, cell, fault, monkeypatch):
-    monkeypatch.setattr(plan_mod.Operator, "__call__", fault)
+    monkeypatch.setattr(plan_mod.Operator, "_apply", fault)
     line = fixtures.run(root, cell)
     assert line["correct"] is False
 
@@ -69,12 +73,12 @@ def test_chain_catches_one_wrong_step(root, monkeypatch):
     _, _, loop = harness.setup(bench, cell, seed=5)
     calls = []
 
-    def fourth_doubled(self, x, permuted=False):
+    def fourth_doubled(self, engine, x, permuted):
         calls.append(1)
-        y = REAL(self, x, permuted)
+        y = REAL(self, engine, x, permuted)
         return 2 * y if len(calls) == 4 else y
 
-    monkeypatch.setattr(plan_mod.Operator, "__call__", fourth_doubled)
+    monkeypatch.setattr(plan_mod.Operator, "_apply", fourth_doubled)
     for i in range(12):
         loop.dispatch(i).block_until_ready()
     found = loop.check()

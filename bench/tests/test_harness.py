@@ -2,13 +2,19 @@
 a traffic mix and a per-layer metric are each added as new files plus new
 BENCHMARK.json entries, without editing a file that is there."""
 import hashlib
+import importlib
 import json
 import os
+import re
+import shutil
 
+import jax.numpy as jnp
 import pytest
 
+from repro.core.spmv import plan as plan_mod
+
 from bench import harness
-from bench.tests import fixtures
+from bench.tests import fixtures, test_correctness
 
 REPO = os.path.dirname(fixtures.BENCH)
 
@@ -20,8 +26,9 @@ def root(tmp_path_factory):
 
 def test_cell_files_found_by_name(root):
     cell = harness.Bench(root).cell(fixtures.CG_CELL)
-    assert cell.config == fixtures.TINY_CONFIGS["hpcg27-6"]
-    assert cell.traffic == fixtures.TINY_TRAFFIC["cg8-shuffled"]
+    assert cell.config == fixtures.tiny_config("hpcg27-104")
+    assert cell.traffic == fixtures.tiny_traffic("cg50-shuffled")
+    assert cell.traffic["max_iter"] == 8
     assert set(cell.limits) == {"x_err"}
     assert [m["name"] for m in cell.end_to_end] == ["setup_s", "cg_solve_s"]
     names = [m["name"] for m in cell.per_layer]
@@ -65,7 +72,12 @@ def test_real_manifest_names_files_that_exist():
     bench = harness.Bench(REPO)
     for w in bench.manifest["workloads"]:
         cell = bench.cell(w["name"])
-        assert cell.limits and cell.traffic["loop"] in ("cg", "pagerank")
+        loop = cell.traffic["loop"]
+        assert os.path.exists(os.path.join(bench.dir, "loops", loop + ".py"))
+        mod = importlib.import_module(f"bench.loops.{loop}")
+        for method in ("warm_up", "dispatch", "least_bytes", "check"):
+            assert callable(getattr(mod.Loop, method))
+        assert cell.limits
         assert cell.traffic["metric"] in {m["name"] for m in cell.end_to_end}
 
 
@@ -85,10 +97,10 @@ def test_added_cell_config_traffic_and_metric(tmp_path):
     before = digest(os.path.join(root, "bench"))
     fixtures.write_json(
         os.path.join(root, "bench", "configs", "hpcg27-5.json"),
-        dict(fixtures.TINY_CONFIGS["hpcg27-6"], nx=5, ny=5, nz=4))
+        dict(fixtures.tiny_config("hpcg27-104"), nx=5, ny=5, nz=4))
     fixtures.write_json(
         os.path.join(root, "bench", "traffic", "cg4-natural.json"),
-        dict(fixtures.TINY_TRAFFIC["cg8-shuffled"], order="natural",
+        dict(fixtures.tiny_traffic("cg50-shuffled"), order="natural",
              max_iter=4, reorder="baseline"))
     fixtures.write_json(
         os.path.join(root, "bench", "cells", "hpcg27-5.cg4-natural.json"),
@@ -119,3 +131,57 @@ def test_added_cell_config_traffic_and_metric(tmp_path):
     assert {"plan_s", "compile_s"} <= set(line["metrics"])
     plain = fixtures.run(root, "hpcg27-5.cg4-natural")
     assert set(plain["metrics"]) == {"setup_s", "cg_solve_s"}
+
+
+def copy_benchmark(dst) -> str:
+    """The real benchmark's files, copied to `dst`, to add to."""
+    shutil.copytree(fixtures.BENCH, os.path.join(dst, "bench"),
+                    ignore=shutil.ignore_patterns("__pycache__", "*.pb"))
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), dst)
+    return str(dst)
+
+
+def test_tiny_root_takes_a_new_cell_with_no_test_edit(tmp_path, monkeypatch):
+    """A cell added as new files and manifest entries only (an existing
+    configuration, the 16-topic traffic over `op.matmul`, its limits, its
+    name in a metric's list) runs in the tests' tiny checkout with no file
+    of the tests edited: sound at k = 16, and neither its bfloat16 control
+    nor a fault planted in `Operator._apply`, which `op.matmul` runs."""
+    repo = copy_benchmark(tmp_path / "repo")
+    cell = "g500-s21.tspr16"
+    fixtures.write_json(
+        os.path.join(repo, "bench", "cells", cell + ".json"),
+        fixtures.read_json(os.path.join(repo, "bench", "cells",
+                                        fixtures.PR_CELL + ".json")))
+    manifest = fixtures.read_json(os.path.join(repo, "BENCHMARK.json"))
+    manifest["workloads"].append({
+        "name": cell, "config": "g500-s21", "traffic": "tspr16", "chips": 1,
+        "why": "new"})
+    for m in manifest["end_to_end"]:
+        if m["name"] == "spmv_ms":
+            m["workloads"].append(cell)
+    fixtures.write_json(os.path.join(repo, "BENCHMARK.json"), manifest)
+
+    root = fixtures.tiny_root(str(tmp_path / "tiny"), repo=repo)
+    assert fixtures.manifest_workloads(root) == \
+        fixtures.manifest_workloads(REPO) + [cell]
+    line = fixtures.run(root, cell)
+    assert line["correct"] is True
+    assert set(line["metrics"]) == {"setup_s", "spmv_ms"}
+    assert fixtures.run(root, cell, dtype=jnp.bfloat16)["correct"] is False
+    monkeypatch.setattr(plan_mod.Operator, "_apply",
+                        test_correctness.half_dropped)
+    assert fixtures.run(root, cell)["correct"] is False
+
+
+def test_tiny_root_refuses_a_config_without_a_tiny_file(tmp_path):
+    repo = copy_benchmark(tmp_path / "repo")
+    manifest = fixtures.read_json(os.path.join(repo, "BENCHMARK.json"))
+    manifest["configs"].append({
+        "name": "g500-s22", "source": "test",
+        "file": "bench/configs/g500-s22.json", "reduced": [], "why": "new"})
+    fixtures.write_json(os.path.join(repo, "BENCHMARK.json"), manifest)
+    missing = os.path.join(repo, "bench", "tests", "tiny", "configs",
+                           "g500-s22.json")
+    with pytest.raises(FileNotFoundError, match=re.escape(missing)):
+        fixtures.tiny_root(str(tmp_path / "tiny"), repo=repo)

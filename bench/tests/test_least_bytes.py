@@ -53,15 +53,26 @@ def test_loop_bytes_do_not_depend_on_the_plan(reorder, engine):
     from repro.core.sparse.csr import CSRMatrix
 
     from bench import matrices
-    from bench.loops import cg, pagerank
+    from bench.loops import cg, pagerank, tspr
 
     rowptr, cols, vals, shape = matrices.generate(
         dict(HPCG, generator="hpcg27", nx=5, ny=4, nz=3), "shuffle-3")
     mat = CSRMatrix(rowptr.astype(np.int32), cols, vals, shape)
     op = plan(SpmvProblem(mat), reorder=reorder, engine=engine,
               cache=False).build(cache=False)
-    tr = {"max_iter": 7, "check_units": 1, "damping": 0.85}
+    tr = {"max_iter": 7, "check_units": 1, "damping": 0.85, "k": 4,
+          "set_share": 0.125}
     assert cg.Loop(op, mat, tr, 1, jnp.float32).least_bytes() == \
         7 * least_bytes.cg_iteration(mat.nnz, 60)
     assert pagerank.Loop(op, mat, tr, 1, jnp.float32).least_bytes() == \
         least_bytes.spmv(mat.nnz, 60, 60)
+    assert tspr.Loop(op, mat, tr, 1, jnp.float32).least_bytes() == \
+        least_bytes.spmm(mat.nnz, 60, 60, 4)
+
+
+
+def test_tspr16_hand_count():
+    """g500-s21's 16-topic block: the values once, 16 columns of X and Y."""
+    nnz, n = 63_539_900, 2 ** 21
+    assert least_bytes.spmm(nnz, n, n, 16) == 4 * (nnz + 16 * 2 * n) \
+        == 522_595_056
