@@ -39,10 +39,15 @@ class CGResult(NamedTuple):
     residual: jax.Array
 
 
-@functools.partial(jax.jit, static_argnames=("matvec", "max_iter"))
+@functools.partial(jax.jit, static_argnames=("max_iter",))
 def cg_solve(matvec: Callable, b: jax.Array, max_iter: int = 100,
              tol: float = 1e-8) -> CGResult:
-    """Standard CG, jit-compiled end-to-end (lax.while_loop)."""
+    """Standard CG, jit-compiled end-to-end (lax.while_loop).
+
+    `matvec` is a pytree argument: a plan's `Operator` or an engine. The
+    arrays of a pytree engine (`dia`) are arguments of the compiled loop;
+    an engine registered static is compiled into it as a constant. Wrap
+    a plain function in `jax.tree_util.Partial`."""
     x0 = jnp.zeros_like(b)
     r0 = b - matvec(x0)
     p0 = r0

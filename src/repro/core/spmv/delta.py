@@ -228,6 +228,26 @@ def _bandwidth(mat: CSRMatrix) -> int:
     return int(bandwidth(mat))
 
 
+def _retune_grown_dia(plan, new_mat: CSRMatrix, perm) -> tuple:
+    """(TunePlan, reordered matrix or None) for the edited matrix. A `dia`
+    operator stores one row of m values a diagonal, so an auto-tuned `dia`
+    plan whose delta adds diagonals was priced for a smaller array than
+    the one it would build: it is re-tuned on the edited matrix (in the
+    plan's order). Every other plan keeps its decision."""
+    from . import tune as tune_mod
+
+    tp = plan.tune
+    if tp.engine != "dia" or plan.engine_request != "auto":
+        return tp, None
+    rmat = new_mat if perm is None else new_mat.permute(perm)
+    rows = np.repeat(np.arange(rmat.m, dtype=np.int64), rmat.row_nnz())
+    grown = tune_mod.diagonal_count(rows, rmat.cols.astype(np.int64),
+                                    rmat.m, rmat.n)
+    if grown <= tp.features.get("diagonals", 0):
+        return tp, rmat
+    return tune_mod.tune(rmat, use_kernel=plan.use_kernel, k=plan.k), rmat
+
+
 def apply_delta(plan, delta: StructureDelta, *,
                 max_churn: float = MAX_CHURN,
                 max_bw_growth: float = MAX_BW_GROWTH):
@@ -276,16 +296,18 @@ def apply_delta(plan, delta: StructureDelta, *,
         if perm is not None and delta.append_rows:
             tail = np.arange(mat.shape[0], new_mat.shape[0], dtype=np.int64)
             perm = np.concatenate([np.asarray(perm, np.int64), tail])
+        tp, rmat = _retune_grown_dia(plan, new_mat, perm)
         key = hashlib.sha1(
             f"{plan.key}:delta:{delta.signature()}".encode()
         ).hexdigest()[:20]
         new_plan = _dc.replace(
             plan, key=key, mat_shape=tuple(new_mat.shape),
             mat_nnz=new_mat.nnz, perm=perm, cache_hit=False,
-            reorder_ms=0.0, tune_ms=0.0,
+            reorder_ms=0.0, tune=tp,
+            tune_ms=0.0 if tp is plan.tune else tp.tune_ms,
             plan_ms=(time.perf_counter() - t0) * 1e3,
-            _mat=new_mat, _rmat=None, _op_state=None)
+            _mat=new_mat, _rmat=rmat, _op_state=None)
         obs.counter("delta.applies").inc()
         sp.set(churn=round(churn, 4), bw_growth=round(growth, 3),
-               key_out=key)
+               key_out=key, retuned=tp is not plan.tune)
         return new_plan

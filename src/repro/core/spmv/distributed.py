@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import logging
 import warnings
 from typing import Optional, Tuple
@@ -281,6 +282,15 @@ class _ReorderedView:
         return self._op.shape
 
 
+@functools.cache
+def _register_static() -> None:
+    # a static pytree, so that `cg_solve` takes it as its operator; here,
+    # not at import: this module does not import JAX
+    import jax
+
+    jax.tree_util.register_static(ShardedOperator)
+
+
 class ShardedOperator:
     """Permutation- and topology-carrying distributed SpMV/SpMM operator.
 
@@ -303,6 +313,7 @@ class ShardedOperator:
                  devices=None):
         import jax.numpy as jnp
 
+        _register_static()
         if devices is not None and len(devices) != layout.topology.devices:
             raise ValueError(f"{len(devices)} devices for a "
                              f"{layout.topology.devices}-device mesh")

@@ -50,11 +50,11 @@ def _registry() -> dict:
     from ...kernels.bcsr_spmv.ops import BcsrOperator
     from ...kernels.bell_spmv.ops import BellOperator
     from ...kernels.sell_spmv.ops import SellOperator
-    from .ops import DeviceCSR, DeviceDense, DeviceELL
+    from .ops import DeviceCSR, DeviceDense, DeviceDIA, DeviceELL
 
     return {c.__name__: c for c in
-            (DeviceCSR, DeviceELL, DeviceDense, SellOperator, BellOperator,
-             BcsrOperator)}
+            (DeviceCSR, DeviceELL, DeviceDIA, DeviceDense, SellOperator,
+             BellOperator, BcsrOperator)}
 
 
 def operator_nbytes(op) -> int:

@@ -4,6 +4,8 @@ Engines (each a builder in the plugin registry, core/registry.py):
   csr    — gather + segment-sum (paper Listing 4 semantics; the CPU
            measurement engine for the reproduction study)
   ell    — padded row-major ELLPACK
+  dia    — diagonal storage: one contiguous window of x per distinct
+           col − row, no index array (orders with few diagonals)
   sell   — SELL-C-σ Pallas kernel (TPU) / jnp oracle (CPU)
   bell   — Block-ELL Pallas kernel (TPU) / jnp oracle (CPU)
   bcsr   — BCSR Pallas kernel (TPU) / jnp oracle (CPU)
@@ -42,7 +44,8 @@ from ..sparse.csr import CSRMatrix
 from ..sparse.sell import to_sell
 from . import ref, tune
 
-Engine = Literal["csr", "ell", "sell", "bell", "bcsr", "dense", "auto"]
+Engine = Literal["csr", "ell", "dia", "sell", "bell", "bcsr", "dense",
+                 "auto"]
 
 
 @functools.partial(jax.jit, static_argnames=("m",))
@@ -65,6 +68,12 @@ def _ell_matmul(ell_cols, ell_vals, x):
     return ref.spmm_ell(ell_cols, ell_vals, x)
 
 
+@functools.partial(jax.jit, static_argnames=("offsets", "m"))
+def _dia_apply(dia_vals, x, offsets, m):
+    return ref.spmv_dia(dia_vals, x, offsets, m)
+
+
+@jax.tree_util.register_static
 class DeviceCSR:
     """Device-resident CSR (COO-expanded) operator.
 
@@ -123,6 +132,7 @@ class DeviceCSR:
         return op
 
 
+@jax.tree_util.register_static
 class DeviceELL:
     def __init__(self, mat: CSRMatrix, dtype=jnp.float32):
         self.m, self.n = mat.shape
@@ -169,6 +179,76 @@ class DeviceELL:
         return op
 
 
+@jax.tree_util.register_pytree_node_class
+class DeviceDIA:
+    """Diagonal (DIA) operator: `offsets`, the distinct col − row sorted
+    ascending (static), and `dia_vals [ndiag * m]`, slot-major like ELL
+    (diagonal d is elements d·m to (d + 1)·m); positions outside the
+    matrix hold 0. One dimension, not [ndiag, m]: a TPU tiles a 2-D array
+    (8, 128), so each row would be copied into x's 1-D layout before the
+    multiply; flat, every diagonal is a contiguous window in that layout
+    and an SpMV is one loop fusion.
+
+    Built in O(nnz + m + n) with no sort: a presence table over the
+    m + n − 1 possible offsets gives each its slot, and one bincount
+    scatters the values (duplicates sum, as in CSR).
+
+    A pytree whose one leaf is `dia_vals`: a jitted solver that takes the
+    operator as an argument (`cg_solve`) reads the values as an argument
+    of its program, not as a constant compiled into it."""
+
+    def __init__(self, mat: CSRMatrix, dtype=jnp.float32):
+        self.m, self.n = mat.shape
+        r = np.repeat(np.arange(mat.m, dtype=np.int64), mat.row_nnz())
+        diag = mat.cols.astype(np.int64) - r + (mat.m - 1)
+        present = np.zeros(max(mat.m + mat.n - 1, 0), dtype=bool)
+        present[diag] = True
+        idx = np.flatnonzero(present)
+        self.offsets = tuple(int(d) for d in idx - (mat.m - 1))
+        slot = np.zeros(present.size, dtype=np.int64)
+        slot[idx] = np.arange(idx.size)
+        vals = np.bincount(slot[diag] * mat.m + r, weights=mat.vals,
+                           minlength=idx.size * mat.m)
+        self.dia_vals = jnp.asarray(vals, dtype=dtype)
+
+    def __call__(self, x: jax.Array) -> jax.Array:
+        with obs.span("kernel.spmv", engine="dia",
+                      diagonals=len(self.offsets)):
+            return _dia_apply(self.dia_vals, x, self.offsets, self.m)
+
+    def matmul(self, x: jax.Array) -> jax.Array:
+        """x: [n, k] -> y: [m, k]: each diagonal's values scale one
+        window of k-wide rows of x."""
+        if x.ndim == 1:
+            return self(x)
+        with obs.span("kernel.spmm", engine="dia", k=int(x.shape[1]),
+                      diagonals=len(self.offsets)):
+            return _dia_apply(self.dia_vals, x, self.offsets, self.m)
+
+    def state(self):
+        meta = {"m": self.m, "n": self.n, "offsets": list(self.offsets)}
+        return meta, {"dia_vals": np.asarray(self.dia_vals)}
+
+    @classmethod
+    def from_state(cls, meta, arrays, dtype=jnp.float32):
+        op = object.__new__(cls)
+        op.m, op.n = meta["m"], meta["n"]
+        op.offsets = tuple(int(d) for d in meta["offsets"])
+        op.dia_vals = jnp.asarray(arrays["dia_vals"], dtype=dtype)
+        return op
+
+    def tree_flatten(self):
+        return (self.dia_vals,), (self.m, self.n, self.offsets)
+
+    @classmethod
+    def tree_unflatten(cls, meta, leaves):
+        op = object.__new__(cls)
+        op.m, op.n, op.offsets = meta
+        (op.dia_vals,) = leaves
+        return op
+
+
+@jax.tree_util.register_static
 class DeviceDense:
     def __init__(self, mat: CSRMatrix, dtype=jnp.float32):
         self.a = jnp.asarray(mat.to_dense(), dtype=dtype)
@@ -209,6 +289,16 @@ def _build_ell(mat: CSRMatrix, dtype=jnp.float32, block_shape=(8, 128),
                sell_sigma=None, use_kernel: str = "auto",
                nnz_bucket: int = 0):
     return DeviceELL(mat, dtype)
+
+
+@register_engine("dia", supports_spmm=True, device="any",
+                 cost_fn=tune.cost_dia, candidates_fn=tune.cands_default,
+                 description="diagonal storage: shifted windows of x, "
+                             "no index array")
+def _build_dia(mat: CSRMatrix, dtype=jnp.float32, block_shape=(8, 128),
+               sell_sigma=None, use_kernel: str = "auto",
+               nnz_bucket: int = 0):
+    return DeviceDIA(mat, dtype)
 
 
 @register_engine("bell", supports_spmm=True, device="tpu",

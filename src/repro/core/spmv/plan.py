@@ -42,6 +42,7 @@ fork.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import json
 import os
@@ -199,12 +200,20 @@ class Operator:
     by hand. `permuted=True` opts out (x already in the reordered space,
     result returned in the reordered space) — the measurement harness path.
     For a baseline/identity plan both paths are the same single engine call.
+
+    A pytree: the engine and the permutation arrays are its children, so
+    a jitted solver that takes the operator as an argument (`cg_solve`)
+    reads the arrays of a pytree engine (`dia`) as arguments of its
+    program; an engine registered static is compiled in as before. The
+    rest rides along with the operator itself, compared by identity, so
+    each operator is traced anew, as a static argument was.
     """
 
     def __init__(self, inner, perm: Optional[np.ndarray], plan: "Plan",
                  build_info: Optional[dict] = None):
         import jax.numpy as jnp
 
+        _register_operator_pytree()
         self.inner = inner
         self.plan = plan
         self.build_info = build_info or {}
@@ -271,6 +280,24 @@ class Operator:
                 yr = engine(xr)
             with jax.named_scope("spmv.permute"):
                 return jnp.take(yr, self._iperm, axis=0)
+
+    def tree_flatten(self):
+        return (self.inner, self._perm, self._iperm), self
+
+    @classmethod
+    def tree_unflatten(cls, src, children):
+        op = object.__new__(cls)
+        op.__dict__.update(src.__dict__)
+        op.inner, op._perm, op._iperm = children
+        return op
+
+
+@functools.cache
+def _register_operator_pytree() -> None:
+    # here, not at import: this module does not import JAX
+    import jax
+
+    jax.tree_util.register_pytree_node_class(Operator)
 
 
 @dataclasses.dataclass
@@ -685,11 +712,11 @@ def _operator_registry() -> dict:
     from ...kernels.bcsr_spmv.ops import BcsrOperator
     from ...kernels.bell_spmv.ops import BellOperator
     from ...kernels.sell_spmv.ops import SellOperator
-    from .ops import DeviceCSR, DeviceDense, DeviceELL
+    from .ops import DeviceCSR, DeviceDense, DeviceDIA, DeviceELL
 
     return {c.__name__: c for c in
-            (DeviceCSR, DeviceELL, DeviceDense, SellOperator, BellOperator,
-             BcsrOperator)}
+            (DeviceCSR, DeviceELL, DeviceDIA, DeviceDense, SellOperator,
+             BellOperator, BcsrOperator)}
 
 
 def _auto_schemes(hints: dict) -> list:

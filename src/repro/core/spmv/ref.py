@@ -79,6 +79,30 @@ def spmm_ell(ell_cols: jax.Array, ell_vals: jax.Array, x: jax.Array) -> jax.Arra
                       precision=HIGHEST).astype(out_dtype)
 
 
+def spmv_dia(dia_vals: jax.Array, x: jax.Array, offsets: tuple,
+             m: int) -> jax.Array:
+    """Diagonal storage: dia_vals [ndiag * m], diagonal d in
+    dia_vals[d * m:(d + 1) * m], its element i holding A[i, i + offsets[d]]
+    (0 where that column is outside the matrix); x [n] or [n, k] ->
+    y [m] or [m, k].
+
+    x is zero-padded once so that every diagonal reads one static,
+    contiguous window of it: no index array and no gather. The products
+    are summed in f32 (at least) in ascending offset order, which for an
+    interior row is CSR's column order."""
+    out_dtype = jnp.promote_types(dia_vals.dtype, x.dtype)
+    acc = jnp.promote_types(out_dtype, jnp.float32)
+    n = x.shape[0]
+    lo = max(0, -min(offsets, default=0))
+    hi = max(0, max(offsets, default=0) + m - n)
+    xp = jnp.pad(x, [(lo, hi)] + [(0, 0)] * (x.ndim - 1)).astype(acc)
+    y = jnp.zeros((m,) + x.shape[1:], acc)
+    for d, off in enumerate(offsets):
+        v = dia_vals[d * m:(d + 1) * m].astype(acc)
+        y = y + (v if x.ndim == 1 else v[:, None]) * xp[lo + off:lo + off + m]
+    return y.astype(out_dtype)
+
+
 def spmv_bell(blocks: jax.Array, block_cols: jax.Array, x2d: jax.Array) -> jax.Array:
     """Block-ELL: blocks [nbr, K, bm, bn]; block_cols [nbr, K];
     x2d [ncb, bn, nv] (x padded & reshaped). Returns y [nbr, bm, nv].

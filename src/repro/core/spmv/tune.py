@@ -109,11 +109,20 @@ def fixed_plan(engine: str, block_shape: tuple = (8, 128),
 
 
 def _label(engine: str, block_shape: tuple, sigma: Optional[int]) -> str:
-    if engine in ("csr", "ell", "dense"):
+    if engine in ("csr", "ell", "dia", "dense"):
         return engine
     if engine == "sell":
         return f"sell_c{block_shape[0]}w{block_shape[1]}s{sigma}"
     return f"{engine}_{block_shape[0]}x{block_shape[1]}"
+
+
+def diagonal_count(rows: np.ndarray, cols: np.ndarray, m: int,
+                   n: int) -> int:
+    """The number of distinct col - row among the entries (rows[i],
+    cols[i]), by one bincount over the m + n - 1 possible offsets:
+    np.unique would sort the nnz, on every scheme of every plan."""
+    return int(np.count_nonzero(np.bincount(
+        cols - rows + (m - 1), minlength=max(m + n - 1, 0))))
 
 
 def matrix_features(mat: CSRMatrix, bm: int = 8, bn: int = 128) -> dict:
@@ -146,6 +155,7 @@ def matrix_features(mat: CSRMatrix, bm: int = 8, bn: int = 128) -> dict:
         "nonempty_blocks": nblocks,
         "block_row_max": int(br_counts.max()) if br_counts.size else 0,
         "num_block_rows": int(br_counts.shape[0]),
+        "diagonals": diagonal_count(r, c, mat.m, mat.n),
     }
 
 
@@ -200,6 +210,14 @@ def cost_sell(feat, block_shape, sigma, sell_pad, k):
     return float(pad * (_VAL + _IDX)
                  + k * (pad * _VAL * _gather(feat, k) * 0.25
                         + feat["m"] * _VAL))
+
+
+def cost_dia(feat, block_shape, sigma, sell_pad, k):
+    # one value a row of every diagonal, no index; each diagonal reads a
+    # contiguous window of x, so x streams once a diagonal, no penalty
+    d = feat["diagonals"]
+    return float(d * feat["m"] * _VAL
+                 + k * (d * feat["n"] * _VAL + feat["m"] * _VAL))
 
 
 def cost_bell(feat, block_shape, sigma, sell_pad, k):
@@ -262,7 +280,7 @@ def candidate_cost(feat: dict, engine: str, block_shape: tuple = (8, 128),
 def enumerate_candidates(mat: CSRMatrix, feat: dict) -> list[dict]:
     """The (engine, shape) grid the tuner searches: every registered engine
     with a cost model contributes its candidates_fn grid, in registration
-    order (built-ins: csr, ell, bell, bcsr, sell, dense)."""
+    order (built-ins: csr, ell, dia, bell, bcsr, sell, dense)."""
     from . import ops  # noqa: F401 — ensure built-in engines are registered
 
     cands = []
@@ -360,7 +378,8 @@ def _tune_impl(mat: CSRMatrix, probe, dtype, use_kernel: str, k: int,
         else:
             source = "probe"
     lab = _label(best["engine"], best["block_shape"], best["sigma"])
-    _sp.set(engine=best["engine"], source=source)
+    _sp.set(engine=best["engine"], source=source,
+            diagonals=feat["diagonals"])
     return TunePlan(engine=best["engine"], block_shape=best["block_shape"],
                     sell_sigma=best["sigma"], cost_bytes=costs[lab],
                     costs=costs, features=feat, source=source,

@@ -36,6 +36,7 @@ def pad_empty_rows(host: BCSR) -> BCSR:
                 shape=host.shape, block_shape=host.block_shape)
 
 
+@jax.tree_util.register_static
 class BcsrOperator:
     """Device-resident BCSR operator: y = A @ x."""
 

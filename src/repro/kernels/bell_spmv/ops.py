@@ -19,6 +19,7 @@ from .kernel import bell_spmm
 from .ref import bell_spmm_ref
 
 
+@jax.tree_util.register_static
 class BellOperator:
     """Device-resident Block-ELL operator: y = A @ x."""
 

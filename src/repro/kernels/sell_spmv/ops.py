@@ -32,6 +32,7 @@ def _segments(chunk_slice, num_slices: int) -> tuple:
                                 n_arrays=1)
 
 
+@jax.tree_util.register_static
 class SellOperator:
     """Device-resident SELL-C-σ operator: y = A @ x."""
 

@@ -70,8 +70,8 @@ def test_one_chip_phases_pass_small(stores, capsys):
     out = capsys.readouterr().out
     assert "FAIL" not in out
     for name in ("plan baseline auto", "plan rcm auto", "engine bell rcm",
-                 "engine bcsr rcm", "engine sell rcm", "cg rcm",
-                 "service rcm"):
+                 "engine bcsr rcm", "engine sell rcm", "engine dia baseline",
+                 "cg rcm", "service rcm"):
         assert f"[{name}] pass" in out, name
 
 

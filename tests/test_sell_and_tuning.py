@@ -1,7 +1,7 @@
 """SELL-C-σ engine, OSKI-style autotuner, and persistent operator cache.
 
 Covers the PR's acceptance criteria:
-  * cross-engine equivalence (csr/ell/sell/bell/bcsr vs the dense numpy
+  * cross-engine equivalence (csr/ell/dia/sell/bell/bcsr vs the dense numpy
     oracle) over the generator suites — including the power-law row-skew
     generator — under every reorder scheme in PAPER_SCHEMES
   * SELL beats padded-ELL storage by >= 2x on power-law skew
@@ -21,7 +21,7 @@ from repro.core.sparse.sell import (pick_chunk_width, sell_padded_nnz,
 from repro.core.spmv.ops import DeviceELL, build_operator, make_engine
 from repro.matrices import generators as G
 
-ENGINES = ["csr", "ell", "sell", "bell", "bcsr"]
+ENGINES = ["csr", "ell", "dia", "sell", "bell", "bcsr"]
 
 MATS = {
     "banded": lambda: G.banded(64, 3, 0),
@@ -212,11 +212,16 @@ def test_auto_engine_probe_mode():
 def test_tuner_prefers_ell_on_uniform_rows_and_not_on_skew():
     from repro.core.spmv.tune import tune
 
-    banded = tune(G.banded(2048, 8, 0))
+    # a full band is diagonal storage's own case (17 diagonals, no
+    # index array); the same band under a random symmetric order keeps
+    # its uniform rows but scatters them over thousands of diagonals,
+    # and padded ELL is then the cheapest
+    band = G.banded(2048, 8, 0)
+    assert tune(band).engine == "dia"
+    assert tune(G.shuffle(band, seed=1)).engine == "ell"
     skew = tune(G.power_law(2048, alpha=1.8, seed=0))
-    assert banded.engine == "ell"
-    # on heavy skew padded-ELL must never win
-    assert skew.engine != "ell"
+    # on heavy skew neither padded ELL nor DIA may win
+    assert skew.engine != "ell" and skew.engine != "dia"
 
 
 def test_operator_cache_hit(tmp_path, monkeypatch):

@@ -25,7 +25,7 @@ from repro.kernels.sell_spmm.ops import pick_k_tile
 from repro.matrices import generators as G
 from repro.serving.spmv_service import SpmvService
 
-ENGINES = ["csr", "ell", "sell", "bell", "bcsr", "dense"]
+ENGINES = ["csr", "ell", "dia", "sell", "bell", "bcsr", "dense"]
 
 MATS = {
     "banded": lambda: G.banded(64, 3, 0),
@@ -123,8 +123,10 @@ def test_cost_model_amortizes_matrix_bytes_over_k():
 def test_cost_model_k1_is_the_spmv_model():
     """k defaults must not perturb the existing per-SpMV ranking."""
     banded = tune(G.banded(2048, 8, 0))
+    shuffled = tune(G.shuffle(G.banded(2048, 8, 0), seed=1))
     skew = tune(G.power_law(2048, alpha=1.8, seed=0))
-    assert banded.engine == "ell" and skew.engine != "ell"
+    assert banded.engine == "dia" and shuffled.engine == "ell"
+    assert skew.engine not in ("ell", "dia")
     assert banded.k == 1 and "@k" not in banded.label()
 
 

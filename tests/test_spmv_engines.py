@@ -1,4 +1,4 @@
-"""SpMV engines vs oracles: csr/ell/bell/bcsr/dense, dtypes, SpMM."""
+"""SpMV engines vs oracles: csr/ell/dia/bell/bcsr/dense, dtypes, SpMM."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,7 +9,7 @@ from repro.core.sparse.csr import CSRMatrix
 from repro.core.spmv.ops import build_operator
 from repro.matrices import generators as G
 
-ENGINES = ["csr", "ell", "dense", "bell", "bcsr"]
+ENGINES = ["csr", "ell", "dia", "dense", "bell", "bcsr"]
 
 MATS = {
     "banded": lambda: G.banded(96, 3, 0),

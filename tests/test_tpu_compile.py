@@ -18,7 +18,10 @@ space smem"); each pallas_call now covers one SMEM segment, so the
 program holds several.
 
 ELL, the XLA engine the planner picks at these shapes, is compiled too:
-its row-major [m, K] scalar gather took the compiler ~100 s here.
+its row-major [m, K] scalar gather took the compiler ~100 s here. So is
+DIA at HPCG's 27 diagonals on the 104^3 grid in its own numbering: with
+its values flat, an SpMV is one loop fusion (a [27, m] array made XLA
+copy each (8, 128)-tiled row into x's 1-D layout first).
 """
 import os
 import time
@@ -43,6 +46,9 @@ BCSR_T = 592_192              # nonempty (8, 128) blocks under rcm
 SELL_SLOTS = 85_072 * 8 * 128  # [W, C] chunks of Graph500 scale 21 at W = 8
 SELL_N = 2 ** 21              # its columns
 ELL_K = 7                     # max row nnz
+HPCG_OFFSETS = tuple(sorted(dz * 104 * 104 + dy * 104 + dx
+                            for dz in (-1, 0, 1) for dy in (-1, 0, 1)
+                            for dx in (-1, 0, 1)))
 
 
 @pytest.fixture(scope="module")
@@ -135,3 +141,32 @@ def test_ell_compiles_for_v5e_in_seconds(sds, k):
     fn.lower(sds((ELL_K, M), jnp.int32), sds((ELL_K, M), jnp.float32),
              x).compile()
     assert time.perf_counter() - t0 < 30
+
+
+@pytest.mark.parametrize("k", [1, 8])
+def test_dia_compiles_for_v5e(sds, k):
+    x = sds((M,), jnp.float32) if k == 1 else sds((M, k), jnp.float32)
+    compiled = ops._dia_apply.lower(
+        sds((len(HPCG_OFFSETS) * M,), jnp.float32), x,
+        offsets=HPCG_OFFSETS, m=M).compile()
+    if k == 1:
+        text = compiled.as_text()
+        assert text[text.index("\nENTRY"):].count(" fusion(") == 1
+    # no copy of the 121 MB of values: temp is a few vectors at most
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * M * k * 4
+
+
+def test_cg_over_dia_takes_the_values_as_an_argument(sds):
+    """The natural-order CG set: the values reach the loop as an argument
+    (a 121 MB constant would be compiled into the program) and no copy
+    of them is made."""
+    from repro.core.measure.cg import cg_solve
+
+    op = ops.DeviceDIA.tree_unflatten(
+        (M, M, HPCG_OFFSETS),
+        (sds((len(HPCG_OFFSETS) * M,), jnp.float32),))
+    compiled = cg_solve.lower(op, sds((M,), jnp.float32), max_iter=50,
+                              tol=0.0).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= len(HPCG_OFFSETS) * M * 4
+    assert mem.temp_size_in_bytes < 8 * M * 4
